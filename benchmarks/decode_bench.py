@@ -244,7 +244,9 @@ def bench_engine_mesh(seed: int = 0, new_tokens: int = 6):
     rng = np.random.default_rng(seed)
     trace = [(16, 0), (32, 1), (64, 2), (16, 4)]
     prompts = [rng.integers(0, cfg.vocab_size, (ln,), dtype=np.int32) for ln, _ in trace]
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.compat import make_mesh
+
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
     out = {}

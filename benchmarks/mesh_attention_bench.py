@@ -2,6 +2,11 @@
 
     PYTHONPATH=src python -m benchmarks.mesh_attention_bench [--json-out PATH]
 
+CPU only: the measured part runs in a child process pinned to
+``JAX_PLATFORMS=cpu`` with 8 fake devices (its parent has imported JAX, so
+on a TPU host the child could not take the chip).  Its wall times are CPU
+times, never device metrics.
+
 Runs a segment-masked (packed two-document) workload against the unmasked
 causal baseline on a (2, 4) fake-device mesh and reports, per commit:
 
@@ -44,7 +49,8 @@ import json, time
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.compat import make_mesh
 from repro.core.masking import MaskSpec
 from repro.core.mesh_attention import MeshAttentionConfig, mesh_attention
 from repro.core import schedule as Sch
@@ -53,7 +59,7 @@ from repro.launch.hlo_analysis import collective_bytes
 import dataclasses
 
 n = 4
-mesh = jax.make_mesh((2, 4), ("data", "sp"))
+mesh = make_mesh((2, 4), ("data", "sp"))
 B, S, H, Hkv, D = 2, 512, 4, 2, 32
 kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(kq, (B, S, H, D))

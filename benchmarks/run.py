@@ -329,22 +329,26 @@ def bench_arch_tiles():
 
 
 def bench_measured_mesh_attention():
-    """Real (CPU, 1-core, 8 fake devices) wall time of the distributed op —
-    a smoke-level sanity check that the machinery runs, not a perf claim."""
+    """CPU only: wall time of the distributed op on 8 fake CPU devices, in a
+    child process — a smoke-level check that the machinery runs, not a
+    device metric.  The parent has imported JAX, so on a TPU host the child
+    could not take the chip; it is pinned to the CPU."""
     import subprocess
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"
     )
     code = r"""
 import time, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.compat import make_mesh
 from repro.core.dispatch import AttentionPlanConfig, attention_in_shard_map
 n=8
-mesh = jax.make_mesh((n,), ("sp",))
+mesh = make_mesh((n,), ("sp",))
 B,S,H,D = 1, 8*256, 4, 32
 q,k,v = (jax.random.normal(kk,(B,S,H,D)) for kk in jax.random.split(jax.random.PRNGKey(0),3))
 for a in (1, 2, 4):

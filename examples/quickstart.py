@@ -25,6 +25,7 @@ except ImportError:  # running from a checkout without `pip install -e .`
 import jax
 import jax.numpy as jnp
 
+from repro.compat import make_mesh
 from repro.core.am import CommModel, table2
 from repro.core.autotune import tune
 from repro.core.dispatch import distributed_attention, plan_from_ctx
@@ -48,7 +49,7 @@ def main():
         print(f"  step {i}: comm={list(step.comms)} compute={list(step.compute)}")
 
     # --- 2. distributed vs single-device (via the dispatch seam) ------------
-    mesh = jax.make_mesh((n,), ("sp",))
+    mesh = make_mesh((n,), ("sp",))
     B, S, H, D = 2, n * 32, 4, 16
     q, k, v = (
         jax.random.normal(kk, (B, S, H, D))

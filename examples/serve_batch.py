@@ -17,6 +17,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 import numpy as np
 
+from repro.compat import make_mesh
 from repro.configs import get_config
 from repro.models import transformer as tfm
 from repro.parallel.context import ParallelCtx
@@ -40,7 +41,7 @@ def main():
     out_single = single.generate(prompts, max_new_tokens=args.new_tokens)
 
     if jax.device_count() >= 8:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                           block_q=8, block_kv=8)
         dist = ServeEngine(cfg, params, ctx=ctx, max_seq=128)

@@ -21,6 +21,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 
+from repro.compat import make_mesh
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.optim.adamw import AdamWConfig
@@ -55,7 +56,7 @@ def main():
     if args.single_device or jax.device_count() < 8:
         ctx = ParallelCtx()
     else:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                           block_q=16, block_kv=16)
     print(f"devices={jax.device_count()} mesh={'none' if ctx.mesh is None else dict(ctx.mesh.shape)}")

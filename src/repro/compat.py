@@ -1,128 +1,46 @@
-"""Single import point normalizing JAX API drift.
+"""Mesh and Pallas-output helpers shared by the whole tree.
 
-Every module in this tree that needs ``shard_map`` (or the other helpers
-below) imports it from here instead of from ``jax`` directly, so the repo
-runs unmodified on both API generations:
-
-  * jax >= 0.5/0.6: ``jax.shard_map`` is a top-level export with the
-    ``check_vma=`` / ``axis_names=`` keywords;
-  * jax <= 0.4.x (this container ships 0.4.37): only
-    ``jax.experimental.shard_map.shard_map`` exists, with the older
-    ``check_rep=`` / ``auto=`` spelling.
-
-The wrapper accepts the NEW spelling everywhere and translates down when
-needed, so call sites are written once against the modern API.
+``make_mesh`` builds meshes whose axes are ``AxisType.Auto``.  Since jax 0.7
+``jax.make_mesh`` defaults to Explicit axes, under which gathers,
+``with_sharding_constraint`` and eager scatters on sharded arrays demand
+explicit ``out_sharding``s; the model code is written for GSPMD propagation
+(Auto), so every mesh is built here, that way.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Sequence
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
 __all__ = [
-    "shard_map",
-    "get_abstract_mesh",
-    "typeof",
+    "make_mesh",
     "vma_struct",
     "abstract_mesh",
-    "supports_nested_manual_grad",
-    "JAX_HAS_TOPLEVEL_SHARD_MAP",
 ]
 
-JAX_HAS_TOPLEVEL_SHARD_MAP = hasattr(jax, "shard_map")
 
-
-def _mesh_axis_names(mesh):
-    names = getattr(mesh, "axis_names", None)
-    if names is None:  # AbstractMesh exposes shape_tuple
-        names = tuple(name for name, _ in mesh.shape_tuple)
-    return tuple(names)
-
-
-if JAX_HAS_TOPLEVEL_SHARD_MAP:
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-                  axis_names: Optional[set] = None):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kw,
-        )
-
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-                  axis_names: Optional[set] = None):
-        """0.4.x translation: ``check_vma`` -> ``check_rep``; the manual-axes
-        set ``axis_names`` -> its complement ``auto`` (axes left to GSPMD)."""
-        kw = {"check_rep": check_vma}
-        if axis_names is not None:
-            kw["auto"] = frozenset(_mesh_axis_names(mesh)) - frozenset(axis_names)
-        return _exp_shard_map(f, mesh, in_specs, out_specs, **kw)
-
-
-def supports_nested_manual_grad() -> bool:
-    """Whether ``jax.grad`` may cross a shard_map nested inside a
-    partial-manual shard_map region.
-
-    0.4.x names the inner op's grad residuals over every mesh axis
-    (``shard_map._all_mesh_names_except_spmd``), clashing with the outer
-    region's manual axes, and the 0.4-era XLA SPMD partitioner fatals on the
-    resulting manual-subgroup shardings.  New jax tracks this through the vma
-    type system.  Callers (e.g. the compressed cross-pod gradient path) gate
-    the nested-manual formulation on this and otherwise fall back to the
-    un-nested equivalent.
-    """
-    return JAX_HAS_TOPLEVEL_SHARD_MAP
-
-
-def typeof(x):
-    """``jax.typeof`` (new) or the abstract value (0.4.x)."""
-    get = getattr(jax, "typeof", None)
-    if get is not None:
-        return get(x)
-    from jax import core
-
-    return core.get_aval(x)
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def vma_struct(shape, dtype, *like):
     """ShapeDtypeStruct whose varying-manual-axes set is the union of the
-    inputs' — required for pallas_call outputs under shard_map(check_vma) on
-    new jax.  0.4.x avals carry no vma and the kwarg does not exist, so the
-    plain struct is returned there.
-    """
-    vma = frozenset().union(*(getattr(typeof(x), "vma", frozenset()) for x in like))
+    inputs' — required for pallas_call outputs under shard_map(check_vma)."""
+    vma = frozenset().union(*(getattr(jax.typeof(x), "vma", frozenset()) for x in like))
     if not vma:
         return jax.ShapeDtypeStruct(shape, dtype)
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """Device-free mesh handle across both AbstractMesh constructor shapes:
-    new jax takes ``(sizes, names)``, 0.4.x takes ``(((name, size), ...))``."""
-    from jax.sharding import AbstractMesh
+    """Device-free mesh handle (Auto axes)."""
+    return AbstractMesh(
+        tuple(axis_sizes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names),
+    )
 
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-def get_abstract_mesh():
-    """Ambient abstract mesh (None when unsupported or not under a mesh).
-
-    Newer jax exposes ``jax.sharding.get_abstract_mesh`` and nested
-    ``shard_map`` calls must reuse the ambient mesh (its axis_types carry
-    which axes are already manual).  0.4.x has no such accessor; callers
-    fall back to their concrete mesh handle, which is what nested
-    ``shard_map`` expected on that generation.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    return get()

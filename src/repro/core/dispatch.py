@@ -32,9 +32,9 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import autotune
 from repro.core import kv_quant
 from repro.core import schedule as S

@@ -226,9 +226,20 @@ def _ring_hop(buf, axis_name: str, perm, mode: str):
         h = x.shape[-1] // 2
         cw = lax.ppermute(x[..., :h], axis_name, perm)
         ccw = lax.ppermute(x[..., h:], axis_name, perm)
-        return jnp.concatenate([cw, ccw], axis=-1)
+        return join_halves(cw, ccw)
 
     return jax.tree.map(hop, buf)
+
+
+def join_halves(lo, hi):
+    """``concatenate([lo, hi], axis=-1)`` written as a pad and a slice
+    update.  XLA compiles the elementwise consumers of a concatenate
+    differently from those of one array, and the online-softmax combine
+    then rounds differently (1 ulp on the CPU); after a slice update they
+    compile as on the single-permute payload, so bidir stays bitwise."""
+    axis = lo.ndim - 1
+    out = jnp.pad(lo, [(0, 0)] * axis + [(0, hi.shape[-1])])
+    return lax.dynamic_update_slice_in_dim(out, hi, lo.shape[-1], axis=axis)
 
 
 def _after_comms(issued, *operands):

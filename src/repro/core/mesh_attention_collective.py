@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import schedule as S
+from repro.core.mesh_attention import join_halves
 from repro.kernels import ops
 from repro.kernels.ref import BAND_INF, NEG_INF
 
@@ -83,7 +84,7 @@ def mesh_attention_collective(
         h = x.shape[-1] // 2
         lo = lax.all_gather(x[..., :h], axis)
         hi_half = lax.all_gather(x[..., h:], axis)
-        return jnp.concatenate([lo, hi_half], axis=-1)
+        return join_halves(lo, hi_half)
 
     # Algorithm 1 lines 1-2: group all-gathers
     qs = gather(q, q_axis)  # [a, B, m, H, D]

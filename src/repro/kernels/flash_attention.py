@@ -1,7 +1,8 @@
 """Pallas TPU flash-attention kernels (forward, backward-dQ, backward-dKV).
 
 TARGET: TPU v5e MXU/VMEM.  Validated on CPU with ``interpret=True`` against
-``kernels/ref.py`` (see tests/test_kernels.py).
+``kernels/ref.py`` (see tests/test_kernels.py); compiled for a v5e by
+tests/test_tpu_compile.py.
 
 Design (TPU-native, not a CUDA port):
   * grid = (batch, q_heads, q_blocks, kv_blocks); the kv dimension is the
@@ -35,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import vma_struct
+from repro.kernels import resolve_interpret
 from repro.kernels.ref import BAND_INF, NEG_INF
 
 DEFAULT_BLOCK_Q = 128
@@ -136,7 +138,7 @@ def _fwd_kernel(
         l_safe = jnp.where(l > 0, l, 1.0)
         o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
         lse = jnp.where(l > 0, m_ref[...] + jnp.log(l_safe), NEG_INF)
-        lse_ref[0, 0] = lse[:, 0].astype(lse_ref.dtype)
+        lse_ref[0, 0, 0] = lse[:, 0].astype(lse_ref.dtype)
 
 
 def _seg_operands(seg_q, seg_kv, block_q, block_kv):
@@ -161,11 +163,13 @@ def flash_attention_fwd(
     stride_kv: int = 1,
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     seg_q: Optional[jnp.ndarray] = None,  # [Sq] int32 segment ids
     seg_kv: Optional[jnp.ndarray] = None,  # [Skv]
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (o [B,Sq,H,D], lse [B,H,Sq])."""
+    """Returns (o [B,Sq,H,D], lse [B,H,Sq]).  ``interpret=None`` follows
+    the platform (see ``resolve_interpret``)."""
+    interpret = resolve_interpret(interpret)
     B, Sq, H, D = q.shape
     _, Skv, Hkv, _ = k.shape
     block_q = min(block_q, Sq)
@@ -189,7 +193,7 @@ def flash_attention_fwd(
     grid = (B, H, nq, nk)
     out_shape = [
         _struct((B, H, Sq, D), q.dtype, q, k, v, band),
-        _struct((B, H, Sq), jnp.float32, q, k, v, band),
+        _struct((B, H, 1, Sq), jnp.float32, q, k, v, band),
     ]
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -208,7 +212,7 @@ def flash_attention_fwd(
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, iq, ik: (b, h, 0, iq)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
@@ -224,7 +228,7 @@ def flash_attention_fwd(
         ),
         name="mesh_flash_fwd",
     )(*operands)
-    return o.transpose(0, 2, 1, 3), lse
+    return o.transpose(0, 2, 1, 3), lse.reshape(B, H, Sq)
 
 
 # --------------------------------------------------------------------------
@@ -266,8 +270,8 @@ def _dq_kernel(
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+        lse = lse_ref[0, 0, 0].astype(jnp.float32)[:, None]
+        delta = delta_ref[0, 0, 0].astype(jnp.float32)[:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -326,8 +330,8 @@ def _dkv_kernel(
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+        lse = lse_ref[0, 0, 0].astype(jnp.float32)[:, None]
+        delta = delta_ref[0, 0, 0].astype(jnp.float32)[:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -364,12 +368,13 @@ def flash_attention_bwd(
     stride_kv: int = 1,
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     delta: Optional[jnp.ndarray] = None,  # [B, Sq, H]
     seg_q: Optional[jnp.ndarray] = None,  # [Sq] int32 segment ids
     seg_kv: Optional[jnp.ndarray] = None,  # [Skv]
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """FlashAttention backward from saved (o, lse): (dq, dk, dv)."""
+    interpret = resolve_interpret(interpret)
     B, Sq, H, D = q.shape
     _, Skv, Hkv, _ = k.shape
     block_q = min(block_q, Sq)
@@ -381,7 +386,10 @@ def flash_attention_bwd(
 
     if delta is None:
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = delta.astype(jnp.float32).transpose(0, 2, 1)  # [B, H, Sq]
+    # row vectors [B, H, 1, Sq]: a (1, block_q) block meets the TPU tiling
+    # rule, where a (1, 1, block_q) block of [B, H, Sq] would not
+    delta = delta.astype(jnp.float32).transpose(0, 2, 1).reshape(B, H, 1, Sq)
+    lse = lse.reshape(B, H, 1, Sq)
 
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -400,8 +408,8 @@ def flash_attention_bwd(
         pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h // group, ik, 0)),
         pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h // group, ik, 0)),
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+        pl.BlockSpec((1, 1, 1, block_q), lambda b, h, iq, ik: (b, h, 0, iq)),
+        pl.BlockSpec((1, 1, 1, block_q), lambda b, h, iq, ik: (b, h, 0, iq)),
     ]
     dq_operands = [band, qt, kt, vt, dot, lse, delta]
     if has_seg:
@@ -442,12 +450,12 @@ def flash_attention_bwd(
             lambda b, hkv, ik, it, g=group, nq_=nq: (b, hkv * g + it // nq_, it % nq_, 0),
         ),
         pl.BlockSpec(
-            (1, 1, block_q),
-            lambda b, hkv, ik, it, g=group, nq_=nq: (b, hkv * g + it // nq_, it % nq_),
+            (1, 1, 1, block_q),
+            lambda b, hkv, ik, it, g=group, nq_=nq: (b, hkv * g + it // nq_, 0, it % nq_),
         ),
         pl.BlockSpec(
-            (1, 1, block_q),
-            lambda b, hkv, ik, it, g=group, nq_=nq: (b, hkv * g + it // nq_, it % nq_),
+            (1, 1, 1, block_q),
+            lambda b, hkv, ik, it, g=group, nq_=nq: (b, hkv * g + it // nq_, 0, it % nq_),
         ),
     ]
     dkv_operands = [band, qt, kt, vt, dot, lse, delta]

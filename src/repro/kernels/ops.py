@@ -6,6 +6,9 @@ Backend policy (``REPRO_KERNELS`` env var or ``set_backend()``):
              tests, but models/benchmarks run the fast XLA reference.
   * "pallas" : Pallas with interpret=True off-TPU (slow; correctness runs).
   * "ref"    : always the jnp oracle.
+``attention_backend()`` names what the policy resolved to on this platform
+(``pallas`` = compiled, ``pallas-interpret`` or ``ref``), so a run can
+record it and a chip run can refuse anything but compiled kernels.
 
 Two API layers:
   * ``flash_attention``  — differentiable (custom_vjp pairing the fwd kernel
@@ -28,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import flash_attention as fa
-from repro.kernels import ref
+from repro.kernels import ref, resolve_interpret
 
 Band = ref.Band
 
@@ -50,17 +53,17 @@ def pallas_enabled() -> bool:
     """Does the current backend policy run Pallas kernels (compiled on TPU,
     or interpret-mode under REPRO_KERNELS=pallas)?  "auto" off-TPU runs the
     fast XLA reference instead — perf-default code paths key off this."""
-    return _use_pallas()[0]
-
-
-def _use_pallas() -> Tuple[bool, bool]:
-    """-> (use_pallas, interpret)"""
-    on_tpu = jax.default_backend() == "tpu"
     if _BACKEND == "ref":
-        return False, False
-    if _BACKEND == "pallas":
-        return True, not on_tpu
-    return on_tpu, False
+        return False
+    return _BACKEND == "pallas" or jax.default_backend() == "tpu"
+
+
+def attention_backend() -> str:
+    """What attention runs as here: "pallas" (compiled), "pallas-interpret"
+    or "ref"."""
+    if not pallas_enabled():
+        return "ref"
+    return "pallas-interpret" if resolve_interpret() else "pallas"
 
 
 def full_band() -> Tuple[int, int, int, int]:
@@ -85,12 +88,11 @@ def block_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     band = jnp.asarray(band, jnp.int32)
-    use_pallas, interpret = _use_pallas()
-    if use_pallas:
+    if pallas_enabled():
         return fa.flash_attention_fwd(
             q, k, v, band,
             scale=scale, stride_q=stride_q, stride_kv=stride_kv,
-            block_q=block_q, block_kv=block_kv, interpret=interpret,
+            block_q=block_q, block_kv=block_kv,
             seg_q=seg_q, seg_kv=seg_kv,
         )
     return ref.attention_ref(
@@ -118,12 +120,11 @@ def block_attention_bwd(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     band = jnp.asarray(band, jnp.int32)
-    use_pallas, interpret = _use_pallas()
-    if use_pallas:
+    if pallas_enabled():
         return fa.flash_attention_bwd(
             q, k, v, o, lse, do, band,
             scale=scale, stride_q=stride_q, stride_kv=stride_kv,
-            block_q=block_q, block_kv=block_kv, interpret=interpret, delta=delta,
+            block_q=block_q, block_kv=block_kv, delta=delta,
             seg_q=seg_q, seg_kv=seg_kv,
         )
     return ref.attention_bwd_ref(

@@ -52,6 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import vma_struct
+from repro.kernels import resolve_interpret
 from repro.kernels.ref import BAND_INF, NEG_INF
 
 __all__ = [
@@ -197,7 +198,7 @@ def _decode_kernel(
         l = l_ref[...]
         l_safe = jnp.where(l > 0, l, 1.0)
         o_ref[0, 0] = acc_ref[...] / l_safe
-        lse_ref[0, 0] = jnp.where(
+        lse_ref[0, 0, 0] = jnp.where(
             l[:, 0] > 0, m_ref[:, 0] + jnp.log(l_safe[:, 0]), NEG_INF
         )
 
@@ -241,8 +242,7 @@ def paged_flash_decode(
         num_splits = default_num_splits(max_pages)
     num_splits = max(1, min(int(num_splits), max_pages))
     pages_per_split = -(-max_pages // num_splits)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     def kv_index_map(b, s, p, bt_ref, pos_ref, off_ref):
         # clamp invisible steps to the nearest VISIBLE logical page so runs of
@@ -283,7 +283,7 @@ def paged_flash_decode(
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, H, D), lambda b, s, p, *_: (b, s, 0, 0)),
-            pl.BlockSpec((1, 1, H), lambda b, s, p, *_: (b, s, 0)),
+            pl.BlockSpec((1, 1, 1, H), lambda b, s, p, *_: (b, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, D), jnp.float32),
@@ -303,7 +303,7 @@ def paged_flash_decode(
         grid_spec=grid_spec,
         out_shape=[
             vma_struct((B, num_splits, H, D), jnp.float32, *like),
-            vma_struct((B, num_splits, H), jnp.float32, *like),
+            vma_struct((B, num_splits, 1, H), jnp.float32, *like),
         ],
         interpret=interpret,
         compiler_params=None
@@ -313,5 +313,5 @@ def paged_flash_decode(
         ),
         name="paged_flash_decode",
     )(*operands)
-    o, lse = combine_split_partials(o_parts, lse_parts)
+    o, lse = combine_split_partials(o_parts, lse_parts[:, :, 0])
     return o.astype(q.dtype), lse

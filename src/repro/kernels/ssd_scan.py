@@ -13,8 +13,9 @@ TPU-native structure:
     scan inside the kernel).
   * chunk length and head dims default to 64/128 lanes (hardware-aligned).
 
-The kernel is forward-only (training uses the autodiff-able jnp dual form in
-models/ssm.py; serving and the CP state hand-off use this kernel on TPU).
+The kernel is forward-only; the model's SSM layers use the autodiff-able jnp
+dual form in models/ssm.py and do not call it yet.  tests/test_tpu_compile.py
+compiles it for a v5e.
 """
 
 from __future__ import annotations
@@ -27,13 +28,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 __all__ = ["ssd_scan_fwd"]
 
 
 def _ssd_kernel(
     A_ref,  # [H] f32 in SMEM
     x_ref,  # [1, 1, c, P]
-    dt_ref,  # [1, 1, c]
+    dt_ref,  # [1, 1, c, 1]
     b_ref,  # [1, 1, c, N]
     c_ref,  # [1, 1, c, N]
     y_ref,  # [1, 1, c, P] out
@@ -50,7 +53,7 @@ def _ssd_kernel(
         h_ref[...] = jnp.zeros_like(h_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)  # [c, P]
-    dt = dt_ref[0, 0].astype(jnp.float32)  # [c]
+    dt = dt_ref[0, 0][:, 0].astype(jnp.float32)  # [c]
     Bm = b_ref[0, 0].astype(jnp.float32)  # [c, N]
     Cm = c_ref[0, 0].astype(jnp.float32)  # [c, N]
     A = A_ref[head]
@@ -95,9 +98,11 @@ def ssd_scan_fwd(
     Cm: jnp.ndarray,  # [B, S, G, N]
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """-> (y [B,S,H,P], final_state [B,H,P,N])."""
+    """-> (y [B,S,H,P], final_state [B,H,P,N]).  ``interpret=None``
+    follows the platform (see ``resolve_interpret``)."""
+    interpret = resolve_interpret(interpret)
     B, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     chunk = min(chunk, S)
@@ -107,7 +112,9 @@ def ssd_scan_fwd(
     group = H // G
 
     xt = x.transpose(0, 2, 1, 3)  # [B, H, S, P]
-    dtt = dt.transpose(0, 2, 1)  # [B, H, S]
+    # a column [B, H, S, 1]: a (chunk, 1) block meets the TPU tiling rule,
+    # where a (1, 1, chunk) block of [B, H, S] would not
+    dtt = dt.transpose(0, 2, 1)[..., None]
     bt = Bm.transpose(0, 2, 1, 3)  # [B, G, S, N]
     ct = Cm.transpose(0, 2, 1, 3)
 
@@ -118,7 +125,7 @@ def ssd_scan_fwd(
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, z: (b, h, z, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, z: (b, h, z)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, z: (b, h, z, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, z, g=group: (b, h // g, z, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, z, g=group: (b, h // g, z, 0)),
         ],

@@ -19,7 +19,12 @@ page pool (preempt-and-recompute under pressure), ``--deadline-ticks`` /
 ``--cancel idx:tick`` exercise the lifecycle paths, ``--chaos-seed N``
 replays a seeded fault trace (squeezes + NaN ticks + dropped grants), and
 ``--check-deterministic`` reruns everything and exits 1 unless statuses,
-streams, and chaos events reproduce exactly — the CI chaos-smoke gate.
+streams, and chaos events reproduce exactly — the CI chaos-smoke gate;
+``--check-preempts`` exits 1 unless the run preempted at least once.
+
+On ``n`` devices the sequence axis spans all of them, and one logical page
+holds ``n * page_size`` tokens: keep that product fixed when a trace's page
+geometry matters (``--fake-devices 8 --page-size 2`` pages 16 tokens).
 """
 
 import argparse
@@ -108,6 +113,10 @@ def main():
                          "engine + fresh chaos injector) and exit nonzero "
                          "unless statuses, token streams, and chaos events "
                          "all match exactly")
+    ap.add_argument("--check-preempts", action="store_true",
+                    help="exit nonzero unless the --stream run preempted at "
+                         "least one request (a pressure trace that never "
+                         "exhausts the pool gates nothing)")
     args = ap.parse_args()
 
     if args.fake_devices:
@@ -120,21 +129,18 @@ def main():
     import numpy as np
 
     from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import launch_context
     from repro.models import transformer as tfm
-    from repro.parallel.context import ParallelCtx
     from repro.serve.config import ServeConfig
     from repro.serve.engine import ServeEngine
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    n = jax.device_count()
-    if n >= 8:
-        mesh = jax.make_mesh((n // 4, 4), ("data", "model"))
-        ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
-                          block_q=16, block_kv=16)
-    else:
-        ctx = ParallelCtx()
+    enable_compile_cache()
+    ctx = launch_context(jax.device_count())
     params = tfm.init_params(cfg, jax.random.PRNGKey(0), ctx=ctx)
     def make_serve(spec_k):
         return ServeConfig(
@@ -201,6 +207,8 @@ def main():
             "ticks": ticks,
             "prefill_traces": {str(k): v for k, v in eng.prefill_trace_counts.items()},
             "decode_traces": eng.decode_trace_count,
+            "attention": ops.attention_backend(),
+            "decode_kernel": eng.decode_kernel,
         }
         if args.prefill_chunk:
             stats = eng.tick_stats()
@@ -250,6 +258,9 @@ def main():
                 "chaos_events": chaos.events if chaos is not None else [],
             }
         print(json.dumps(summary))
+        if args.check_preempts and not eng.kv_cache_stats().get("preemptions"):
+            print("check-preempts: the run preempted no request", file=sys.stderr)
+            return 1
         if args.check_deterministic:
             # gate: a fresh engine + fresh injector replaying the identical
             # (seed, trace, faults) triple must reproduce every outcome

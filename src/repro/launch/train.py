@@ -4,8 +4,10 @@
         [--reduced] [--steps 100] [--seq 128] [--batch 8] \
         [--ckpt-dir DIR] [--compress] [--multi-pod]
 
-On real hardware the mesh comes from `make_production_mesh()`; on this
-container pass --fake-devices N to emulate (sets XLA_FLAGS; must be first).
+The mesh comes from ``launch.mesh.launch_context``: the sequence axis spans
+every device below a pod (4-way on a 2x2 v5e host), the production mesh from
+256 chips.  On a CPU, --fake-devices N emulates N devices (sets XLA_FLAGS
+before jax is imported).
 """
 
 import argparse
@@ -39,10 +41,11 @@ def main():
     import jax
 
     from repro.configs import get_config
-    from repro.launch.mesh import make_context, make_production_mesh
+    from repro.kernels import ops
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import launch_context
     from repro.optim.adamw import AdamWConfig
     from repro.parallel.compression import CompressionConfig
-    from repro.parallel.context import ParallelCtx
     from repro.train.loop import TrainConfig, fit
 
     cfg = get_config(args.arch)
@@ -50,22 +53,11 @@ def main():
         cfg = cfg.reduced()
 
     n = jax.device_count()
-    if n >= 512 and args.multi_pod:
-        ctx = make_context(multi_pod=True, mesh_a=args.tile_a, attn_impl=args.attn)
-    elif n >= 256:
-        ctx = make_context(multi_pod=False, mesh_a=args.tile_a, attn_impl=args.attn)
-    elif n >= 8:
-        shape, axes = ((2, 2, 2), ("pod", "data", "model")) if args.multi_pod else ((2, 4), ("data", "model"))
-        mesh = jax.make_mesh(shape, axes)
-        ctx = ParallelCtx(
-            mesh=mesh,
-            batch_axes=("pod", "data") if args.multi_pod else ("data",),
-            sp_axis="model", mesh_a=args.tile_a, attn_impl=args.attn,
-            block_q=16, block_kv=16,
-        )
-    else:
-        ctx = ParallelCtx()
-    print(f"devices={n} mesh={'none' if ctx.mesh is None else dict(ctx.mesh.shape)}")
+    enable_compile_cache()
+    ctx = launch_context(n, multi_pod=args.multi_pod, mesh_a=args.tile_a,
+                         attn_impl=args.attn)
+    print(f"devices={n} mesh={'none' if ctx.mesh is None else dict(ctx.mesh.shape)} "
+          f"attention={ops.attention_backend()}")
 
     tcfg = TrainConfig(
         steps=args.steps, seq=args.seq, batch=args.batch,

@@ -182,7 +182,7 @@ def _moe_ep_manual(x, p, cfg: ModelConfig, ctx: ParallelCtx):
     import jax
     from jax import lax
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     m = cfg.moe
     act = _ACT[cfg.mlp_act]

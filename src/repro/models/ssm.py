@@ -26,9 +26,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from repro.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig

@@ -15,8 +15,6 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 __all__ = ["ParallelCtx"]
 
 
@@ -110,7 +108,7 @@ class ParallelCtx:
         happens under a mesh context (e.g. inside a partial-manual
         shard_map over the pod axis), the AMBIENT abstract mesh must be
         used — its axis_types carry which axes are already manual."""
-        am = compat.get_abstract_mesh()
-        if am is not None and am.shape_tuple:
+        am = jax.sharding.get_abstract_mesh()
+        if am.shape_tuple:
             return am
         return self.mesh

@@ -25,10 +25,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 __all__ = ["pipeline_apply", "pipeline_stages"]
 

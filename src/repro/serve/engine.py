@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import dispatch
@@ -180,11 +181,10 @@ class ServeEngine:
         self.kv_dtype = serve.kv_dtype
         self._quantized = serve.kv_dtype != "fp"
         self.dequant_fallbacks = 0  # quantized ticks served by the gather ref
-        self._native_decode = (
-            dispatch._resolve_decode_kernel(
-                getattr(self.ctx, "decode_kernel", "auto"), paged=serve.paged
-            ) == "native"
-            if serve.paged else False
+        # the decode kernel "auto" resolved to on this platform: "native"
+        # (split-K Pallas), "gather" (paged reference) or "band" (dense)
+        self.decode_kernel = dispatch._resolve_decode_kernel(
+            getattr(self.ctx, "decode_kernel", "auto"), paged=serve.paged
         )
         self.allocator: Optional[PageAllocator] = None
         if serve.paged:
@@ -238,6 +238,7 @@ class ServeEngine:
             paged=self.allocator.layout if self.allocator else None,
             kv_dtype=serve.kv_dtype,
         )
+        self._cache = {k: self._place(k, v) for k, v in self._cache.items()}
         self._cur = np.zeros((self.num_slots, 1), np.int32)  # last token per slot
         self._depth = np.zeros((self.num_slots,), np.int64)  # host view of pos
         # per-slot consecutive zero-accept verify ticks (speculative decode:
@@ -364,12 +365,23 @@ class ServeEngine:
             out[key] = pool.at[:, dst].set(pool[:, src], mode="drop")
         return out
 
+    def _place(self, key: str, value):
+        """Put a cache leaf where the jitted steps return it: attention K/V
+        (and their scales) sharded on the sequence axis along dim 2, every
+        other leaf replicated.  A leaf placed otherwise has another type on
+        the mesh, and the next step would trace again."""
+        if self.ctx.mesh is None:
+            return jax.tree.map(jnp.asarray, value)
+        seq_sharded = key in ("k", "v", "k_scale", "v_scale")
+        spec = P(None, None, self.ctx.sp_axis) if seq_sharded else P()
+        return jax.device_put(value, NamedSharding(self.ctx.mesh, spec))
+
     def _sync_block_table(self):
         """Upload the allocator's block table when it moved since last sync."""
         if self.allocator is None or self.allocator.version == self._bt_version:
             return
         self._cache = dict(self._cache)
-        self._cache["bt"] = jnp.asarray(self.allocator.device_table(self.num_slots))
+        self._cache["bt"] = self._place("bt", self.allocator.device_table(self.num_slots))
         self._bt_version = self.allocator.version
         self.bt_uploads += 1
 
@@ -957,7 +969,7 @@ class ServeEngine:
             self._sync_block_table()
             if not decodable:
                 return 0
-        if self._quantized and not self._native_decode:
+        if self._quantized and self.decode_kernel != "native":
             self.dequant_fallbacks += 1  # gather-path dequant served this tick
         nxt, self._cache, logits, ok = self._decode(
             self.params, self._cache, jnp.asarray(self._cur)
@@ -1071,7 +1083,7 @@ class ServeEngine:
                 req.spec_proposed += len(d)
                 self.spec_proposed += len(d)
         self.verify_launches += 1
-        if self._quantized and not self._native_decode:
+        if self._quantized and self.decode_kernel != "native":
             self.dequant_fallbacks += 1  # gather-path dequant served this tick
         out = self._verify(
             self.params,

@@ -17,6 +17,8 @@ import traceback
 
 import numpy as np
 
+from repro.compat import make_mesh
+
 
 def _setup():
     import jax
@@ -44,14 +46,14 @@ def check_mesh_attention_forward():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     from repro.core.mesh_attention import MeshAttentionConfig, mesh_attention
     from repro.core.tiling import factorizations, stripe_permutation, unstripe_permutation
     from repro.kernels import ref
 
     n = 8
-    mesh = jax.make_mesh((n,), ("sp",))
+    mesh = make_mesh((n,), ("sp",))
     B, S, H, Hkv, D = 2, n * 16, 4, 2, 16
     key = jax.random.PRNGKey(0)
     kq, kk, kv = jax.random.split(key, 3)
@@ -94,14 +96,14 @@ def check_mesh_attention_backward():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     from repro.core.mesh_attention import MeshAttentionConfig, mesh_attention
     from repro.core.tiling import factorizations, stripe_permutation, unstripe_permutation
     from repro.kernels import ref
 
     n = 8
-    mesh = jax.make_mesh((n,), ("sp",))
+    mesh = make_mesh((n,), ("sp",))
     B, S, H, Hkv, D = 1, n * 8, 4, 2, 8
     key = jax.random.PRNGKey(1)
     kq, kk, kv = jax.random.split(key, 3)
@@ -158,7 +160,7 @@ def check_mesh_attention_pallas_interpret():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     from repro.core.mesh_attention import MeshAttentionConfig, mesh_attention
     from repro.core.tiling import stripe_permutation, unstripe_permutation
@@ -167,7 +169,7 @@ def check_mesh_attention_pallas_interpret():
     ops.set_backend("pallas")
     try:
         n, a = 4, 2
-        mesh = jax.make_mesh((n,), ("sp",))
+        mesh = make_mesh((n,), ("sp",))
         B, S, H, Hkv, D = 1, n * 16, 2, 1, 8
         key = jax.random.PRNGKey(2)
         kq, kk, kv = jax.random.split(key, 3)
@@ -220,13 +222,13 @@ def check_ring_equals_mesh_a1():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     from repro.core.mesh_attention import MeshAttentionConfig, mesh_attention
     from repro.core.ring_attention import ring_config
 
     n = 8
-    mesh = jax.make_mesh((n,), ("sp",))
+    mesh = make_mesh((n,), ("sp",))
     B, S, H, D = 1, n * 8, 2, 8
     key = jax.random.PRNGKey(3)
     q, k, v = (jax.random.normal(kk, (B, S, H, D)) for kk in jax.random.split(key, 3))
@@ -251,13 +253,13 @@ def check_ulysses():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     from repro.core.ulysses import ulysses_attention
     from repro.kernels import ref
 
     n = 2  # capped by Hkv=2
-    mesh = jax.make_mesh((n,), ("sp",))
+    mesh = make_mesh((n,), ("sp",))
     B, S, H, Hkv, D = 2, n * 16, 4, 2, 16
     key = jax.random.PRNGKey(4)
     kq, kk, kv = jax.random.split(key, 3)
@@ -291,13 +293,13 @@ def check_striped_decode():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     from repro.core.decode_attention import striped_cache_decode, striped_cache_update
     from repro.kernels import ref
 
     n = 4
-    mesh = jax.make_mesh((n,), ("sp",))
+    mesh = make_mesh((n,), ("sp",))
     B, H, Hkv, D = 2, 4, 2, 8
     cap = 8  # local slots -> max context n*cap = 32
     T = 20
@@ -348,13 +350,13 @@ def check_decode_edge():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     from repro.core.decode_attention import sharded_cache_decode, sharded_cache_update
     from repro.kernels import ref
 
     n = 8
-    mesh = jax.make_mesh((n,), ("sp",))
+    mesh = make_mesh((n,), ("sp",))
     B, H, Hkv, D = 2, 4, 2, 8
     m = 4  # local slots: global capacity n*m = 32
     T = 12
@@ -495,7 +497,7 @@ def check_serve_stream():
     ]
     new_tokens = 6
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
     eng = ServeEngine(cfg, params, ctx=ctx, max_seq=128, num_slots=3)
@@ -539,7 +541,7 @@ def check_dispatch_seam():
     from repro.parallel.context import ParallelCtx
 
     n = 8
-    mesh = jax.make_mesh((n,), ("sp",))
+    mesh = make_mesh((n,), ("sp",))
     B, S, H, Hkv, D = 2, n * 16, 4, 2, 16
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(21), 3)
     q = jax.random.normal(kq, (B, S, H, D))
@@ -576,7 +578,7 @@ def check_dispatch_seam():
             assert err < 2e-5, (name, err)
 
         # ulysses routes when the head cap allows (2 devices over Hkv=2)
-        mesh2 = jax.make_mesh((2,), ("sp",))
+        mesh2 = make_mesh((2,), ("sp",))
         ctx2 = ParallelCtx(mesh=mesh2, sp_axis="sp", attn_impl="ulysses",
                            block_q=16, block_kv=16)
         cfg2 = plan_from_ctx(ctx2, causal=False, layout="contiguous")
@@ -624,7 +626,7 @@ def check_pipeline_parallel():
 
     L, D, M, mb = 8, 16, 6, 4
     n_stages = 4
-    mesh = jax.make_mesh((n_stages,), ("pipe",))
+    mesh = make_mesh((n_stages,), ("pipe",))
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     params = {
         "w": jax.random.normal(ks[0], (L, D, D)) / D**0.5,
@@ -666,7 +668,7 @@ def check_collective_mode():
     == single-device oracle AND == the ring-decomposed implementation."""
     import jax
     import jax.numpy as jnp
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.mesh_attention import MeshAttentionConfig, mesh_attention
@@ -676,8 +678,8 @@ def check_collective_mode():
 
     a, b = 2, 4
     n = a * b
-    mesh2d = jax.make_mesh((a, b), ("aq", "akv"))
-    mesh1d = jax.make_mesh((n,), ("sp",))
+    mesh2d = make_mesh((a, b), ("aq", "akv"))
+    mesh1d = make_mesh((n,), ("sp",))
     B, S, H, Hkv, D = 2, n * 16, 4, 2, 16
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(11), 3)
     q = jax.random.normal(kq, (B, S, H, D))
@@ -736,7 +738,7 @@ def check_mla_latent_wire():
     from repro.parallel.context import ParallelCtx
 
     cfg = get_config("minicpm3-4b").reduced()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     base = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                        block_q=8, block_kv=8)
     wire = dataclasses.replace(base, mla_latent_wire=True)
@@ -764,7 +766,7 @@ def check_moe_ep_manual():
 
     cfg = get_config("qwen2-moe-a2.7b").reduced()
     cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0, mode="ep"))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
     params = tfm.init_params(cfg, jax.random.PRNGKey(0), ctx=ctx)
@@ -801,12 +803,12 @@ def check_train_distributed():
     cfg = get_config("granite-8b").reduced()
 
     def ctx_pods():
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         return ParallelCtx(mesh=mesh, batch_axes=("pod", "data"), sp_axis="model",
                            block_q=8, block_kv=8)
 
     def ctx_flat():
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         return ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                            block_q=8, block_kv=8)
 
@@ -847,7 +849,7 @@ def check_serve_distributed():
 
     single = ServeEngine(cfg, params, max_seq=64).generate(prompts, max_new_tokens=6)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
     dist = ServeEngine(cfg, params, ctx=ctx, max_seq=64).generate(prompts, max_new_tokens=6)
@@ -865,13 +867,13 @@ def check_mask_prune():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.masking import MaskSpec
     from repro.core.mesh_attention import MeshAttentionConfig, mesh_attention
     from repro.kernels import ref
 
     n = 4  # sequence-parallel width of the (2, 4) mesh's model axis
-    mesh = jax.make_mesh((2, 4), ("data", "sp"))
+    mesh = make_mesh((2, 4), ("data", "sp"))
     B, S, H, Hkv, D = 2, 64, 4, 2, 8
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(31), 3)
     q = jax.random.normal(kq, (B, S, H, D))
@@ -957,14 +959,14 @@ def check_overlap_exact():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import schedule as Sch
     from repro.core.masking import MaskSpec
     from repro.core.mesh_attention import MeshAttentionConfig, mesh_attention
     from repro.core.mesh_attention_collective import mesh_attention_collective
 
     n = 4
-    mesh = jax.make_mesh((2, 4), ("data", "sp"))
+    mesh = make_mesh((2, 4), ("data", "sp"))
     B, S, H, Hkv, D = 2, 64, 4, 2, 8
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(57), 3)
     q = jax.random.normal(kq, (B, S, H, D))
@@ -1024,7 +1026,7 @@ def check_overlap_exact():
         detail[name] = {"modes": list(Sch.COMM_OVERLAP_MODES), "bitwise": True}
 
     # Algorithm-1 collective mode: the knob maps onto the group all-gathers
-    mesh2d = jax.make_mesh((2, 4), ("aq", "akv"))
+    mesh2d = make_mesh((2, 4), ("aq", "akv"))
     col_outs = {}
     for mode in Sch.COMM_OVERLAP_MODES:
         fcol = shard_map(
@@ -1064,7 +1066,7 @@ def check_packed_prefill():
         rng.integers(0, cfg.vocab_size, (ln,), dtype=np.int32) for ln in (16, 8, 8)
     ]
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
     eng = ServeEngine(cfg, params, ctx=ctx, max_seq=128, num_slots=3)
@@ -1112,7 +1114,7 @@ def check_paged_serve():
     ]
     new_tokens = 6
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
 
@@ -1201,7 +1203,7 @@ def check_continuous_prefill():
     arrivals = [t for _, t in trace]
     new_tokens = 6
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
 
@@ -1293,7 +1295,7 @@ def check_spec_decode():
     arrivals = [0, 1, 2]
     new_tokens = 12
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
 
@@ -1379,7 +1381,7 @@ def check_quant_kv():
     # error is ~0.04, so 0.25 is a conservative end-to-end ceiling
     logit_bound = 0.25
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
 
@@ -1501,7 +1503,7 @@ def check_chaos_serve():
     ]
     new_tokens = 12
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("data",), sp_axis="model",
                       block_q=8, block_kv=8)
 

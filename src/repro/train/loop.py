@@ -28,9 +28,9 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map, supports_nested_manual_grad
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import make_batch
 from repro.models import transformer as tfm
@@ -70,10 +70,6 @@ def make_train_step(cfg: ModelConfig, ctx: ParallelCtx, opt_cfg: AdamWConfig,
         and ctx.mesh is not None
         and "pod" in ctx.mesh.shape
         and ctx.mesh.shape["pod"] > 1
-        # the compressed path differentiates the model INSIDE a manual-pod
-        # shard_map; on jax 0.4.x that nesting cannot lower (see compat) and
-        # the step falls back to the plain uncompressed all-reduce
-        and supports_nested_manual_grad()
     )
 
     def grads_and_metrics(params, batch, the_ctx):
@@ -163,14 +159,24 @@ def fit(
     monitor = StepMonitor(StragglerPolicy(action="checkpoint"))
 
     init = lambda: tfm.init_params(cfg, jax.random.PRNGKey(tcfg.seed), dtype=tcfg.param_dtype, ctx=ctx)
+    err = {}
     if ctx.mesh is not None:
         abstract = jax.eval_shape(init)
         shardings = shd.param_shardings(abstract, ctx, "train")
         params = jax.jit(init, out_shardings=shardings)()
+        # optimizer and error state live on the mesh like the params, as the
+        # step returns them: state placed elsewhere would make step 1 compile
+        # the step again
+        replicated = NamedSharding(ctx.mesh, P())
+        opt_state = jax.jit(init_opt_state, out_shardings=OptState(
+            replicated, shardings, shardings))(params)
+        if tcfg.compression:
+            err = jax.jit(init_error_state, out_shardings=shardings)(params)
     else:
         params = init()
-    opt_state = init_opt_state(params)
-    err = init_error_state(params) if tcfg.compression else jax.tree.map(lambda _: jnp.zeros(()), {})
+        opt_state = init_opt_state(params)
+        if tcfg.compression:
+            err = init_error_state(params)
     start_step = 0
 
     if tcfg.ckpt_dir is not None:

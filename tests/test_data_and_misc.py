@@ -10,6 +10,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.compat import make_mesh
 from repro.configs import get_config
 from repro.data.pipeline import batch_spec_shapes, make_batch
 from repro.parallel.context import ParallelCtx
@@ -65,7 +66,7 @@ def test_eff_batch_axes_divisibility(pod, data):
     if pod * data > jax.device_count():
         # mesh construction needs real devices; emulate with math-only check
         return
-    mesh = jax.make_mesh((pod, data), ("pod", "data"))
+    mesh = make_mesh((pod, data), ("pod", "data"))
     ctx = ParallelCtx(mesh=mesh, batch_axes=("pod", "data"), sp_axis=None)
     for b in (1, 2, 3, 4, 6, 8, 12, 16):
         axes = ctx.eff_batch_axes(b)
@@ -158,3 +159,24 @@ def test_stripe_window_mask_composition():
             kt = perm[kc * m : (kc + 1) * m]
             want = (qt[:, None] >= kt[None, :]) & (qt[:, None] - kt[None, :] < W)
             assert (got == want).all(), (qc, kc)
+
+
+def test_compile_cache_dir_is_the_checkout_or_the_env(tmp_path, monkeypatch):
+    """The default cache lives in the checkout; an installed copy outside a
+    checkout leaves it off; ``JAX_COMPILATION_CACHE_DIR`` wins when set."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    root = Path(__file__).resolve().parents[1]
+    assert compile_cache.checkout_cache_dir() == str(root / ".jax_cache")
+
+    installed = tmp_path / "lib" / "python3.12" / "site-packages" / "repro" / "launch"
+    installed.mkdir(parents=True)
+    monkeypatch.setattr(compile_cache, "__file__", str(installed / "compile_cache.py"))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.checkout_cache_dir() is None
+    assert compile_cache.enable_compile_cache() is None
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    assert compile_cache.enable_compile_cache() == str(tmp_path / "cache")

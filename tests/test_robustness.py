@@ -416,3 +416,27 @@ def test_churn_drains_and_ok_streams_match_oracle(granite, mode, seed):
     for g, p in zip(got, prompts):
         if g.status == "ok":
             assert g.generated == _oracle_stream(granite, mode, p), (mode, seed)
+
+
+# --------------------------------------------------------------------------
+# launcher: the pressure gate must fail a trace that never preempts
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("num_pages,rc", [(7, 0), (12, 1)])
+def test_launcher_check_preempts_gate(num_pages, rc, monkeypatch):
+    """Each request needs 3 pages of 16 tokens: three slots outgrow 7 pages
+    (preempts, exit 0) and fit in 12 (no preemption, exit 1)."""
+    import sys
+
+    from repro.launch import compile_cache
+    from repro.launch import serve as launch_serve
+
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--reduced", "--stream", "--trace", "32:0,32:0,32:1,32:2",
+        "--slots", "3", "--paged", "--page-size", "16",
+        "--num-pages", str(num_pages), "--prefill-chunk", "16",
+        "--oversubscribe", "2.0", "--check-preempts",
+    ])
+    assert launch_serve.main() == rc
