@@ -35,6 +35,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import spans
 from repro.configs.base import ModelConfig
 from repro.core import dispatch
 from repro.core.am import CommModel
@@ -73,6 +74,10 @@ def select_victim(slots, allocator, protect=()):
     if not cands:
         return None
     return min(cands)[3]
+
+# engine totals each engine.tick span reports as its deltas (ServeEngine._counters)
+_TICK_COUNTERS = ("prefill_launches", "pages_allocated", "cow_copies", "prefix_hit_pages",
+                  "bt_uploads", "preemptions", "retraced")
 
 # mid-prefill slots park their cache position past any capacity: the shared
 # decode step still ticks their row, but every write guard (pos < n*m) drops
@@ -262,7 +267,6 @@ class ServeEngine:
         self.prefill_launches = 0
         self.prefill_launch_tokens = 0
         self.chunk_launches = 0
-        self.chunk_launch_tokens = 0
         # speculative decode accounting (engine-wide; per-request twins live
         # on Request/RequestResult)
         self.verify_launches = 0
@@ -380,10 +384,11 @@ class ServeEngine:
         """Upload the allocator's block table when it moved since last sync."""
         if self.allocator is None or self.allocator.version == self._bt_version:
             return
-        self._cache = dict(self._cache)
-        self._cache["bt"] = self._place("bt", self.allocator.device_table(self.num_slots))
-        self._bt_version = self.allocator.version
-        self.bt_uploads += 1
+        with spans.span("engine.bt_upload"):
+            self._cache = dict(self._cache)
+            self._cache["bt"] = self._place("bt", self.allocator.device_table(self.num_slots))
+            self._bt_version = self.allocator.version
+            self.bt_uploads += 1
 
     def _aux_inputs(self, batch_size: int) -> Dict:
         """Frontend stub inputs (audio frames / vision patches)."""
@@ -554,6 +559,7 @@ class ServeEngine:
             prompt, max_new_tokens, arrival_tick,
             deadline_ticks=deadline_ticks, priority=priority,
         )
+        req.queued_since = spans.now()
         return req.rid
 
     @property
@@ -800,28 +806,33 @@ class ServeEngine:
     def _prefill_single(self, slot: int, req: Request) -> int:
         """Legacy one-row-per-request prefill (exact/frontend archs)."""
         bucket = self.scheduler.bucket_for(len(req.prompt))
-        self.prefill_launches += 1
-        self.prefill_launch_tokens += bucket
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, : len(req.prompt)] = req.prompt
-        shared = self._alloc_pages(slot, req) if self.paged else 0
-        self._sync_block_table()
-        fn = self._get_prefill(bucket)
-        out = fn(
-            self.params,
-            self._cache,
-            jnp.asarray(toks),
-            jnp.asarray(len(req.prompt), jnp.int32),
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(shared, jnp.int32),
-        )
-        if self.capture_logits:
-            self._cache, first, row = out
-            self.debug_logits.setdefault(req.rid, []).append(np.asarray(row))
-        else:
-            self._cache, first = out
-        self._depth[slot] = len(req.prompt)
-        return int(np.asarray(first)[0, 0])
+        with spans.span("engine.prefill", bucket=bucket, k=1, tokens=len(req.prompt),
+                        rids=str(req.rid)):
+            self.prefill_launches += 1
+            self.prefill_launch_tokens += bucket
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, : len(req.prompt)] = req.prompt
+            shared = self._alloc_pages(slot, req) if self.paged else 0
+            self._sync_block_table()
+            fn = self._get_prefill(bucket)
+            out = fn(
+                self.params,
+                self._cache,
+                jnp.asarray(toks),
+                jnp.asarray(len(req.prompt), jnp.int32),
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(shared, jnp.int32),
+            )
+            if self.capture_logits:
+                self._cache, first, row = out
+            else:
+                self._cache, first = out
+            with spans.span("engine.prefill.wait"):
+                tok = int(np.asarray(first)[0, 0])
+            if self.capture_logits:
+                self.debug_logits.setdefault(req.rid, []).append(np.asarray(row))
+            self._depth[slot] = len(req.prompt)
+            return tok
 
     def _prefill_group(self, group) -> List[int]:
         """Packed prefill: the group's prompts concatenate into one bucket
@@ -829,37 +840,42 @@ class ServeEngine:
         slot.  Returns the first generated token per request."""
         lens = [len(req.prompt) for _, req in group]
         bucket = self.scheduler.bucket_for(sum(lens))
-        self.prefill_launches += 1
-        self.prefill_launch_tokens += bucket
         k = len(group)
-        toks = np.zeros((1, bucket), np.int32)
-        off = 0
-        for (_, req), ln in zip(group, lens):
-            toks[0, off : off + ln] = req.prompt
-            off += ln
-        shared = [
-            self._alloc_pages(slot, req) if self.paged else 0 for slot, req in group
-        ]
-        self._sync_block_table()
-        fn = self._get_prefill_packed(bucket, k)
-        out = fn(
-            self.params,
-            self._cache,
-            jnp.asarray(toks),
-            jnp.asarray(lens, jnp.int32),
-            jnp.asarray([slot for slot, _ in group], jnp.int32),
-            jnp.asarray(shared, jnp.int32),
-        )
-        if self.capture_logits:
-            self._cache, firsts, rows = out
-            rows_np = np.asarray(rows)
-            for d, (_, req) in enumerate(group):
-                self.debug_logits.setdefault(req.rid, []).append(rows_np[d])
-        else:
-            self._cache, firsts = out
-        for (slot, req), ln in zip(group, lens):
-            self._depth[slot] = ln
-        return [int(t) for t in np.asarray(firsts)]
+        with spans.span("engine.prefill", bucket=bucket, k=k, tokens=sum(lens),
+                        rids=" ".join(str(req.rid) for _, req in group)):
+            self.prefill_launches += 1
+            self.prefill_launch_tokens += bucket
+            toks = np.zeros((1, bucket), np.int32)
+            off = 0
+            for (_, req), ln in zip(group, lens):
+                toks[0, off : off + ln] = req.prompt
+                off += ln
+            shared = [
+                self._alloc_pages(slot, req) if self.paged else 0 for slot, req in group
+            ]
+            self._sync_block_table()
+            fn = self._get_prefill_packed(bucket, k)
+            out = fn(
+                self.params,
+                self._cache,
+                jnp.asarray(toks),
+                jnp.asarray(lens, jnp.int32),
+                jnp.asarray([slot for slot, _ in group], jnp.int32),
+                jnp.asarray(shared, jnp.int32),
+            )
+            if self.capture_logits:
+                self._cache, firsts, rows = out
+            else:
+                self._cache, firsts = out
+            with spans.span("engine.prefill.wait"):
+                firsts_np = np.asarray(firsts)
+            if self.capture_logits:
+                rows_np = np.asarray(rows)
+                for d, (_, req) in enumerate(group):
+                    self.debug_logits.setdefault(req.rid, []).append(rows_np[d])
+            for (slot, req), ln in zip(group, lens):
+                self._depth[slot] = ln
+            return [int(t) for t in firsts_np]
 
     def _record_first_token(self, slot: int, req: Request, tok: int, finished) -> None:
         """First generated token off prefill logits (one-shot or final
@@ -905,19 +921,18 @@ class ServeEngine:
                 pos_set[slot] = req.ingest_len
                 finishing.append((slot, req))
         self.chunk_launches += 1
-        self.chunk_launch_tokens += B * C  # device tokens (incl. pad rows)
         self._sync_block_table()  # paged: admission allocated this plan's pages
         out = self._chunk_step(
             self.params, self._cache, jnp.asarray(tokens), jnp.asarray(starts),
             jnp.asarray(lens), jnp.asarray(wstarts), jnp.asarray(pos_set),
         )
-        logits_np = None
         if self.capture_logits:
             self._cache, first, logits, ok = out
-            logits_np = np.asarray(logits)
         else:
             self._cache, first, ok = out
-        first_np = np.asarray(first)
+        with spans.span("engine.chunk.wait"):
+            first_np = np.asarray(first)
+        logits_np = np.asarray(logits) if self.capture_logits else None
         ok_np = np.asarray(ok)
         n_first = 0
         for slot, req in finishing:
@@ -959,45 +974,49 @@ class ServeEngine:
             # Under oversubscription (or a chaos squeeze) an allocation may
             # find the pool dry — preempt victims and retry; a preempted
             # slot drops out of this tick's decodable set
-            copies = []
-            for slot in decodable:
-                if self.scheduler.slots[slot] is None:
-                    continue  # preempted by an earlier slot's ensure
-                self._ensure_append_robust(slot, int(self._depth[slot]), copies)
-            decodable = [s for s in decodable if self.scheduler.slots[s] is not None]
-            self._apply_copies(copies)
+            with spans.span("engine.pages"):
+                copies = []
+                for slot in decodable:
+                    if self.scheduler.slots[slot] is None:
+                        continue  # preempted by an earlier slot's ensure
+                    self._ensure_append_robust(slot, int(self._depth[slot]), copies)
+                decodable = [s for s in decodable if self.scheduler.slots[s] is not None]
+                self._apply_copies(copies)
             self._sync_block_table()
             if not decodable:
                 return 0
         if self._quantized and self.decode_kernel != "native":
             self.dequant_fallbacks += 1  # gather-path dequant served this tick
-        nxt, self._cache, logits, ok = self._decode(
-            self.params, self._cache, jnp.asarray(self._cur)
-        )
-        nxt_np = np.asarray(nxt)
-        ok_np = np.asarray(ok)
-        logits_np = np.asarray(logits) if self.capture_logits else None
-        tokens = 0
-        for slot in decodable:
-            req = self.scheduler.slots[slot]
-            if self.nan_guard and not bool(ok_np[slot]):
-                # non-finite logits: retire ONLY this slot; every other row's
-                # token came off the same launch and is bitwise what it would
-                # have been (batch rows are independent)
-                self.numeric_errors += 1
-                finished.append(self._finish(slot, status="numeric_error"))
-                continue
-            self._depth[slot] += 1
-            tok = int(nxt_np[slot, 0])
-            if logits_np is not None:
-                self.debug_logits.setdefault(req.rid, []).append(logits_np[slot, 0])
-            req.generated.append(tok)
-            req.token_ticks.append(self._tick)
-            tokens += 1
-            self._cur[slot, 0] = tok
-            if self._req_done(req, tok):
-                finished.append(self._finish(slot))
-        return tokens
+        with spans.span("engine.decode"):
+            nxt, self._cache, logits, ok = self._decode(
+                self.params, self._cache, jnp.asarray(self._cur)
+            )
+            with spans.span("engine.decode.wait"):
+                nxt_np = np.asarray(nxt)
+            with spans.span("engine.decode.post"):
+                ok_np = np.asarray(ok)
+                logits_np = np.asarray(logits) if self.capture_logits else None
+                tokens = 0
+                for slot in decodable:
+                    req = self.scheduler.slots[slot]
+                    if self.nan_guard and not bool(ok_np[slot]):
+                        # non-finite logits: retire ONLY this slot; every other row's
+                        # token came off the same launch and is bitwise what it would
+                        # have been (batch rows are independent)
+                        self.numeric_errors += 1
+                        finished.append(self._finish(slot, status="numeric_error"))
+                        continue
+                    self._depth[slot] += 1
+                    tok = int(nxt_np[slot, 0])
+                    if logits_np is not None:
+                        self.debug_logits.setdefault(req.rid, []).append(logits_np[slot, 0])
+                    req.generated.append(tok)
+                    req.token_ticks.append(self._tick)
+                    tokens += 1
+                    self._cur[slot, 0] = tok
+                    if self._req_done(req, tok):
+                        finished.append(self._finish(slot))
+                return tokens
 
     def _spec_decode_tick(self, decodable, finished, prefill_tokens) -> int:
         """Speculative tick: draft per slot (prompt-lookup n-gram), verify
@@ -1009,42 +1028,43 @@ class ServeEngine:
         suspended after ``spec_max_misses`` dry ticks, or no leftover tick
         budget) — so low-acceptance traffic degrades to baseline, not
         below it.  Returns tokens generated this tick."""
-        drafts = {}
-        for slot in decodable:
-            if self.spec_max_misses is not None:
-                m = self._spec_misses[slot]
-                period = 16 * self.spec_max_misses
-                if m >= self.spec_max_misses:
-                    # tripped: suspend drafting until the next global probe
-                    # boundary (negative counter counts the cooldown down).
-                    # Aligning every slot's wake-up to tick % period == 0
-                    # batches probes into ONE shared verify launch — a verify
-                    # tick costs the whole batch, so staggered per-slot
-                    # probes would each bill a full launch for one row.
-                    self._spec_misses[slot] = -(period - self._tick % period)
+        with spans.span("engine.draft"):
+            drafts = {}
+            for slot in decodable:
+                if self.spec_max_misses is not None:
+                    m = self._spec_misses[slot]
+                    period = 16 * self.spec_max_misses
+                    if m >= self.spec_max_misses:
+                        # tripped: suspend drafting until the next global probe
+                        # boundary (negative counter counts the cooldown down).
+                        # Aligning every slot's wake-up to tick % period == 0
+                        # batches probes into ONE shared verify launch — a verify
+                        # tick costs the whole batch, so staggered per-slot
+                        # probes would each bill a full launch for one row.
+                        self._spec_misses[slot] = -(period - self._tick % period)
+                        continue
+                    if m < 0:
+                        # cooldown lands on max_misses-1: ONE missed probe
+                        # re-trips immediately, a fully-accepted probe
+                        # re-enables drafting outright
+                        self._spec_misses[slot] = (
+                            self.spec_max_misses - 1 if m == -1 else m + 1
+                        )
+                        continue
+                req = self.scheduler.slots[slot]
+                # cap so the furthest write position stays inside the slot's
+                # reserved capacity: at most max_new_tokens positions past prompt
+                rem = req.max_new_tokens - len(req.generated)
+                k_cap = min(self.spec_k, rem)
+                if k_cap < 2:
                     continue
-                if m < 0:
-                    # cooldown lands on max_misses-1: ONE missed probe
-                    # re-trips immediately, a fully-accepted probe
-                    # re-enables drafting outright
-                    self._spec_misses[slot] = (
-                        self.spec_max_misses - 1 if m == -1 else m + 1
-                    )
-                    continue
-            req = self.scheduler.slots[slot]
-            # cap so the furthest write position stays inside the slot's
-            # reserved capacity: at most max_new_tokens positions past prompt
-            rem = req.max_new_tokens - len(req.generated)
-            k_cap = min(self.spec_k, rem)
-            if k_cap < 2:
-                continue
-            d = propose_ngram(req.prompt, req.generated, k_cap - 1)
-            if d:
-                drafts[slot] = d
-        # draft tokens only spend LEFTOVER tick budget: decode rows and chunk
-        # tokens were planned first, so the PR6 TTFT bound is untouched
-        granted = self.scheduler.plan_spec(drafts, len(decodable), prefill_tokens)
-        granted = {s: d for s, d in granted.items() if d}
+                d = propose_ngram(req.prompt, req.generated, k_cap - 1)
+                if d:
+                    drafts[slot] = d
+            # draft tokens only spend LEFTOVER tick budget: decode rows and chunk
+            # tokens were planned first, so the PR6 TTFT bound is untouched
+            granted = self.scheduler.plan_spec(drafts, len(decodable), prefill_tokens)
+            granted = {s: d for s, d in granted.items() if d}
         if not granted:
             return self._vanilla_decode_tick(decodable, finished)
         K = self.spec_k
@@ -1059,102 +1079,105 @@ class ServeEngine:
             starts[slot] = self._depth[slot]
             lens[slot] = 1 + len(d)
         if self.paged:
-            copies = []
-            for slot in decodable:
-                if self.scheduler.slots[slot] is None:
-                    continue  # preempted by an earlier slot's ensure
-                self._ensure_span_robust(
-                    slot, int(self._depth[slot]), int(lens[slot]), copies
-                )
-            live = [s for s in decodable if self.scheduler.slots[s] is not None]
-            if len(live) < len(decodable):
-                for s in decodable:
-                    if self.scheduler.slots[s] is None:
-                        lens[s] = 0  # preempted rows write/commit nothing
-                decodable = live
-            self._apply_copies(copies)
+            with spans.span("engine.pages"):
+                copies = []
+                for slot in decodable:
+                    if self.scheduler.slots[slot] is None:
+                        continue  # preempted by an earlier slot's ensure
+                    self._ensure_span_robust(
+                        slot, int(self._depth[slot]), int(lens[slot]), copies
+                    )
+                live = [s for s in decodable if self.scheduler.slots[s] is not None]
+                if len(live) < len(decodable):
+                    for s in decodable:
+                        if self.scheduler.slots[s] is None:
+                            lens[s] = 0  # preempted rows write/commit nothing
+                    decodable = live
+                self._apply_copies(copies)
             self._sync_block_table()
             if not decodable:
                 return 0
-        for slot in decodable:
-            d = granted.get(slot, [])
-            if d:
-                req = self.scheduler.slots[slot]
-                req.spec_proposed += len(d)
-                self.spec_proposed += len(d)
-        self.verify_launches += 1
-        if self._quantized and self.decode_kernel != "native":
-            self.dequant_fallbacks += 1  # gather-path dequant served this tick
-        out = self._verify(
-            self.params,
-            self._cache,
-            jnp.asarray(tokens),
-            jnp.asarray(starts),
-            jnp.asarray(lens),
-        )
-        logits_np = None
-        if self.capture_logits:
-            y, commit, self._cache, v_logits, ok = out
-            logits_np = np.asarray(v_logits)
-        else:
-            y, commit, self._cache, ok = out
-        y_np = np.asarray(y)
-        commit_np = np.asarray(commit)
-        ok_np = np.asarray(ok)
-        generated = 0
-        for slot in decodable:
-            req = self.scheduler.slots[slot]
-            if self.nan_guard and not bool(ok_np[slot]):
-                # non-finite verify logits: commit nothing for this slot,
-                # retire it alone (other rows commit bitwise-unchanged)
-                self.numeric_errors += 1
-                finished.append(self._finish(slot, status="numeric_error"))
-                continue
-            committed = int(commit_np[slot])
-            drafted = int(lens[slot]) - 1
-            if drafted:
-                accepted = committed - 1  # draft tokens that matched greedy
-                req.spec_accepted += accepted
-                self.spec_accepted += accepted
-                # a MISS is any verify tick with a rejection: the accept
-                # distribution is bimodal (a live loop verifies fully, a
-                # cold history verifies ~nothing), so full-accept cleanly
-                # splits the regimes — and partial-accept ticks barely pay
-                # for the batch-wide verify launch anyway
-                if accepted == drafted:
-                    self._spec_misses[slot] = 0
-                else:
-                    self._spec_misses[slot] += 1
-            self._depth[slot] += committed
-            done = False
-            for i in range(committed):
-                tok = int(y_np[slot, i])
-                if logits_np is not None:
-                    self.debug_logits.setdefault(req.rid, []).append(
-                        logits_np[slot, i]
-                    )
-                req.generated.append(tok)
-                req.token_ticks.append(self._tick)  # same tick: all one launch
-                generated += 1
-                self._cur[slot, 0] = tok
-                if self._req_done(req, tok):
-                    # EOS (or cap) mid-commit: later accepted tokens are
-                    # discarded; their cache writes sit past the final depth
-                    # and are band-invisible / freed by the rollback below
-                    self._depth[slot] -= committed - (i + 1)
-                    done = True
-                    finished.append(self._finish(slot))
-                    break
-            if done:
-                continue
-            if self.paged and drafted:
-                # free pages the verify wrote past the accepted prefix —
-                # sharers never see them (append pages are never registered
-                # for prefix sharing), but held rejected pages would leak
-                # capacity until retirement.  No device sync here: every
-                # launch site re-syncs the block table before launching.
-                self.allocator.rollback(slot, int(self._depth[slot]))
-        return generated
+        with spans.span("engine.verify"):
+            for slot in decodable:
+                d = granted.get(slot, [])
+                if d:
+                    req = self.scheduler.slots[slot]
+                    req.spec_proposed += len(d)
+                    self.spec_proposed += len(d)
+            self.verify_launches += 1
+            if self._quantized and self.decode_kernel != "native":
+                self.dequant_fallbacks += 1  # gather-path dequant served this tick
+            out = self._verify(
+                self.params,
+                self._cache,
+                jnp.asarray(tokens),
+                jnp.asarray(starts),
+                jnp.asarray(lens),
+            )
+            if self.capture_logits:
+                y, commit, self._cache, v_logits, ok = out
+            else:
+                y, commit, self._cache, ok = out
+            with spans.span("engine.verify.wait"):
+                y_np = np.asarray(y)
+            with spans.span("engine.verify.post"):
+                logits_np = np.asarray(v_logits) if self.capture_logits else None
+                commit_np = np.asarray(commit)
+                ok_np = np.asarray(ok)
+                generated = 0
+                for slot in decodable:
+                    req = self.scheduler.slots[slot]
+                    if self.nan_guard and not bool(ok_np[slot]):
+                        # non-finite verify logits: commit nothing for this slot,
+                        # retire it alone (other rows commit bitwise-unchanged)
+                        self.numeric_errors += 1
+                        finished.append(self._finish(slot, status="numeric_error"))
+                        continue
+                    committed = int(commit_np[slot])
+                    drafted = int(lens[slot]) - 1
+                    if drafted:
+                        accepted = committed - 1  # draft tokens that matched greedy
+                        req.spec_accepted += accepted
+                        self.spec_accepted += accepted
+                        # a MISS is any verify tick with a rejection: the accept
+                        # distribution is bimodal (a live loop verifies fully, a
+                        # cold history verifies ~nothing), so full-accept cleanly
+                        # splits the regimes — and partial-accept ticks barely pay
+                        # for the batch-wide verify launch anyway
+                        if accepted == drafted:
+                            self._spec_misses[slot] = 0
+                        else:
+                            self._spec_misses[slot] += 1
+                    self._depth[slot] += committed
+                    done = False
+                    for i in range(committed):
+                        tok = int(y_np[slot, i])
+                        if logits_np is not None:
+                            self.debug_logits.setdefault(req.rid, []).append(
+                                logits_np[slot, i]
+                            )
+                        req.generated.append(tok)
+                        req.token_ticks.append(self._tick)  # same tick: all one launch
+                        generated += 1
+                        self._cur[slot, 0] = tok
+                        if self._req_done(req, tok):
+                            # EOS (or cap) mid-commit: later accepted tokens are
+                            # discarded; their cache writes sit past the final depth
+                            # and are band-invisible / freed by the rollback below
+                            self._depth[slot] -= committed - (i + 1)
+                            done = True
+                            finished.append(self._finish(slot))
+                            break
+                    if done:
+                        continue
+                    if self.paged and drafted:
+                        # free pages the verify wrote past the accepted prefix —
+                        # sharers never see them (append pages are never registered
+                        # for prefix sharing), but held rejected pages would leak
+                        # capacity until retirement.  No device sync here: every
+                        # launch site re-syncs the block table before launching.
+                        self.allocator.rollback(slot, int(self._depth[slot]))
+                return generated
 
     def step(self) -> List[RequestResult]:
         """One engine tick: admission, prompt ingestion, then one jitted
@@ -1166,68 +1189,115 @@ class ServeEngine:
         Continuous mode (``serve.prefill_chunk``) parks newly admitted slots
         past cache capacity and streams their prompt in ``prefill_chunk``-
         token chunks under ``serve.tick_token_budget``; a slot joins the
-        decode batch the same tick its last chunk lands."""
+        decode batch the same tick its last chunk lands.
+
+        Under a profiler session the tick is an ``engine.tick`` span carrying
+        its counters, with a span per phase (``repro.spans``)."""
+        with spans.span("engine.tick") as tick:
+            if not tick.recording:
+                return self._step()[0]
+            t, before = self._tick, self._counters()
+            finished, admitted, decodable = self._step()
+            tick.set(
+                tick=t, admitted=admitted, decodable=decodable,
+                prefill_tokens=self.tick_prefill_tokens[-1],
+                decode_tokens=self.tick_decode_tokens[-1], finished=len(finished),
+                **{k: b - a for k, a, b in zip(_TICK_COUNTERS, before, self._counters())},
+            )
+            return finished
+
+    def _counters(self):
+        """The engine totals that ``engine.tick`` reports as deltas
+        (``_TICK_COUNTERS``)."""
+        a = self.allocator
+        return (
+            self.prefill_launches + self.chunk_launches,
+            a.fresh_allocs if a else 0,
+            a.cow_copies if a else 0,
+            a.shared_hits if a else 0,
+            self.bt_uploads,
+            self.preemptions,
+            self.decode_trace_count + self.chunk_trace_count + self.verify_trace_count
+            + sum(self.prefill_trace_counts.values()),
+        )
+
+    def _step(self):
+        """The tick; returns (finished results, admitted, decode batch size)."""
         finished: List[RequestResult] = []
         prefill_tokens = 0
         decode_tokens = 0
-        # 0. fault injection (testing only) + lifecycle expiry
-        if self.chaos is not None:
-            self.chaos.on_tick(self)
-        for req in self.scheduler.take_expired(self._tick):
-            self.deadline_expired += 1
-            finished.append(self._finish_queued(req))
-        for slot, req in enumerate(self.scheduler.slots):
-            if (
-                req is not None
-                and req.deadline_ticks is not None
-                and self._tick - req.arrival_tick >= req.deadline_ticks
-            ):
+        with spans.span("engine.admit"):
+            # 0. fault injection (testing only) + lifecycle expiry
+            if self.chaos is not None:
+                self.chaos.on_tick(self)
+            for req in self.scheduler.take_expired(self._tick):
                 self.deadline_expired += 1
-                finished.append(self._finish(slot, status="deadline"))
-        # 1. admission + prompt ingestion
-        assigned = self.scheduler.admit(self._tick)
-        for req in self.scheduler.take_rejected():
-            self.rejected_requests += 1
-            finished.append(self._finish_queued(req))
-        for slot, _ in assigned:
-            self._spec_misses[slot] = 0  # fresh request: drafting re-enabled
-        if self.prefill_chunk is not None:
+                finished.append(self._finish_queued(req))
+            for slot, req in enumerate(self.scheduler.slots):
+                if (
+                    req is not None
+                    and req.deadline_ticks is not None
+                    and self._tick - req.arrival_tick >= req.deadline_ticks
+                ):
+                    self.deadline_expired += 1
+                    finished.append(self._finish(slot, status="deadline"))
+            # 1. admission + prompt ingestion
+            assigned = self.scheduler.admit(self._tick)
+            for req in self.scheduler.take_rejected():
+                self.rejected_requests += 1
+                finished.append(self._finish_queued(req))
             for slot, req in assigned:
-                shared = 0
-                if self.paged:
-                    try:
-                        shared = self._alloc_pages_robust(slot, req)
-                    except PoolExhausted:
-                        # nothing evictable (fresh squeeze / lone giant):
-                        # hand the slot back and retry on a later tick
-                        self.scheduler.preempt(slot)
-                        continue
-                if shared:
-                    shared = self._resident_shared_len(slot, shared)
-                self._shared_len[slot] = shared
-                # fully-shared chunks never launch, but the LAST context token
-                # always runs forward — its logits seed the first decode
-                req.prefill_pos = min(shared, req.ingest_len - 1)
-            if assigned:
-                # park mid-prefill rows so the shared decode's writes drop
-                idx = jnp.asarray([slot for slot, _ in assigned], jnp.int32)
-                self._cache = dict(self._cache)
-                self._cache["pos"] = self._cache["pos"].at[idx].set(_PARKED)
-            decodable = [
-                s
-                for s in self.scheduler.active_slots()
-                if self.scheduler.slots[s].prefill_pos
-                >= self.scheduler.slots[s].ingest_len
-            ]
-            plan = self.scheduler.plan_chunks(len(decodable))
-            if plan and self.chaos is not None and self.chaos.drop_grants(self._tick):
-                # injected scheduler fault: this tick's chunk grants vanish;
-                # progress resumes next tick (the head-of-line guarantee is
-                # per-plan, so a dropped plan only delays, never deadlocks)
-                self.chaos_dropped_grants += len(plan)
-                plan = []
+                self._spec_misses[slot] = 0  # fresh request: drafting re-enabled
+                if req.queued_since is not None:
+                    spans.record("request.queued", req.queued_since, rid=req.rid,
+                                 prompt_len=len(req.prompt))
+                    req.queued_since = None
+            if self.prefill_chunk is not None:
+                for slot, req in assigned:
+                    shared = 0
+                    if self.paged:
+                        try:
+                            shared = self._alloc_pages_robust(slot, req)
+                        except PoolExhausted:
+                            # nothing evictable (fresh squeeze / lone giant):
+                            # hand the slot back and retry on a later tick
+                            self.scheduler.preempt(slot)
+                            continue
+                    if shared:
+                        shared = self._resident_shared_len(slot, shared)
+                    self._shared_len[slot] = shared
+                    # fully-shared chunks never launch, but the LAST context token
+                    # always runs forward — its logits seed the first decode
+                    req.prefill_pos = min(shared, req.ingest_len - 1)
+                if assigned:
+                    # park mid-prefill rows so the shared decode's writes drop
+                    idx = jnp.asarray([slot for slot, _ in assigned], jnp.int32)
+                    self._cache = dict(self._cache)
+                    self._cache["pos"] = self._cache["pos"].at[idx].set(_PARKED)
+                decodable = [
+                    s
+                    for s in self.scheduler.active_slots()
+                    if self.scheduler.slots[s].prefill_pos
+                    >= self.scheduler.slots[s].ingest_len
+                ]
+                plan = self.scheduler.plan_chunks(len(decodable))
+                if plan and self.chaos is not None and self.chaos.drop_grants(self._tick):
+                    # injected scheduler fault: this tick's chunk grants vanish;
+                    # progress resumes next tick (the head-of-line guarantee is
+                    # per-plan, so a dropped plan only delays, never deadlocks)
+                    self.chaos_dropped_grants += len(plan)
+                    plan = []
+            elif self._can_pack:
+                groups = self.scheduler.pack_groups(
+                    assigned, pack_max=self.pack_max, plan=self.pack_plan
+                )
+            else:
+                groups = [[x] for x in assigned]
+        if self.prefill_chunk is not None:
             if plan:
-                ingested, n_first = self._run_chunks(plan, finished)
+                with spans.span("engine.chunk", tokens=sum(p[3] for p in plan),
+                                rids=" ".join(str(p[1].rid) for p in plan)):
+                    ingested, n_first = self._run_chunks(plan, finished)
                 prefill_tokens += ingested
                 decode_tokens += n_first  # first tokens off final-chunk logits
                 # final chunks join the decode batch this same tick
@@ -1238,12 +1308,6 @@ class ServeEngine:
                     >= self.scheduler.slots[s].ingest_len
                 ]
         else:
-            if self._can_pack:
-                groups = self.scheduler.pack_groups(
-                    assigned, pack_max=self.pack_max, plan=self.pack_plan
-                )
-            else:
-                groups = [[x] for x in assigned]
             for group in groups:
                 if self._can_pack:
                     firsts = self._prefill_group(group)
@@ -1272,8 +1336,9 @@ class ServeEngine:
         self.tick_decode_tokens.append(decode_tokens)
         self._tick += 1
         if self.health_every and self._tick % self.health_every == 0:
-            self.health()  # raises on any invariant violation
-        return finished
+            with spans.span("engine.health"):
+                self.health()  # raises on any invariant violation
+        return finished, len(assigned), len(decodable)
 
     def run(self) -> Dict[int, RequestResult]:
         """Drain the queue; returns {rid: RequestResult}."""

@@ -77,6 +77,9 @@ class Request:
     # request (acceptance rate = accepted / proposed)
     spec_proposed: int = 0
     spec_accepted: int = 0
+    # ``repro.spans`` clock at submit() while a profiler session ran; the
+    # engine turns it into a ``request.queued`` span at admission
+    queued_since: Optional[float] = None
 
     @property
     def done(self) -> bool:
