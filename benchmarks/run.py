@@ -421,11 +421,11 @@ def bench_serve():
     return payload
 
 
-# ---- decode kernel: gather vs paged-native split-K --------------------------------
+# ---- decode kernel: gather vs paged-native --------------------------------------
 
 
 def bench_decode():
-    """One decode tick over a paged KV pool, gather vs the native split-K
+    """One decode tick over a paged KV pool, gather vs the native paged
     kernel, at several depth mixes and pool occupancies: measured tokens/s
     plus modeled HBM bytes/token (depth- vs capacity-proportional)."""
     from benchmarks.decode_bench import run_bench

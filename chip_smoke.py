@@ -7,7 +7,7 @@
 One chip.  granite-8b (d_model 4096, 32 heads over 8 KV heads, head_dim 128,
 d_ff 14336, vocab 49152) at its published widths; only depth is cut, and
 the cut is printed.  Weights are random from ``--seed``.
-  * decode kernel: the paged split-K decode kernel alone at the model's
+  * decode kernel: the paged decode kernel alone at the model's
     heads (32 over 8 KV heads, head_dim 128), bf16 and int8 pools on the
     engine's page geometry, slot depths 3 to 2080, against a float64
     reference.  Random-weight logits barely move when attention is wrong

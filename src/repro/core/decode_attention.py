@@ -44,7 +44,7 @@ Both decode entries take a ``kernel`` selector:
   * ``"gather"`` / ``"band"`` (the defaults) — the original paths: paged
     gathers the row's pages into a dense local view, then both run the band
     kernel (one vmapped call per row under vector pos).
-  * ``"native"`` — the split-K Pallas kernel (``kernels/paged_decode.py``)
+  * ``"native"`` — the paged Pallas kernel (``kernels/paged_decode.py``)
     reads the block table in-kernel and indexes the page pool directly; the
     dense cache routes through the SAME kernel by viewing each ``[m]`` row as
     one implicit page run (reshape + identity block table).  Falls back to
@@ -215,7 +215,7 @@ def _maybe_pruned_partial(
 
 
 def _native_enabled(kernel: str) -> bool:
-    """The split-K kernel serves ``kernel="native"`` except under the pure-jnp
+    """The paged kernel serves ``kernel="native"`` except under the pure-jnp
     oracle backend, where the gather/band path (the exact reference the kernel
     is validated against) stands in."""
     if kernel in ("gather", "band"):
@@ -237,13 +237,13 @@ def sharded_cache_decode(
     window: Optional[int] = None,
     scale: Optional[float] = None,
     prune: bool = True,
-    kernel: str = "band",  # band | native (split-K over implicit page runs)
+    kernel: str = "band",  # band | native (paged kernel over implicit page runs)
 ) -> jnp.ndarray:
     """One decode step: partial attention per shard + lse-weighted psum.
 
     ``kernel="native"`` views each row's dense slice as ONE implicit page run
-    (reshape + identity block table) and runs the split-K paged kernel — same
-    band math, no per-row vmap, mixed depths spread over the split grid.
+    (reshape + identity block table) and runs the paged kernel — same
+    band math, no per-row vmap, each row walks only its visible pages.
     """
     i = lax.axis_index(axis_name) if axis_name is not None else 0
     m = k_cache.shape[1]
@@ -388,7 +388,7 @@ def paged_cache_decode(
     """Paged decode partial + psum combine.  ``kernel="gather"`` materializes
     each row's dense local view from its pages and runs the identical banded
     partial the dense path uses (the correctness oracle); ``"native"`` hands
-    the pool and the block table straight to the split-K Pallas kernel — no
+    the pool and the block table straight to the paged Pallas kernel — no
     gathered intermediate, HBM traffic follows allocated depth.  Quantized
     pools hand their scale tables along: the native kernel dequantizes in
     VMEM after each page's DMA, the gather path dequantizes in the gather."""
@@ -590,7 +590,7 @@ def paged_cache_chunk_decode(
 ) -> jnp.ndarray:
     """Paged chunk attention: gather the row's pages into the dense local view
     and run the identical banded chunk partial (chunks are a prefill-side
-    path — the split-K decode kernel stays single-token).  Quantized pools
+    path — the paged decode kernel stays single-token).  Quantized pools
     dequantize in the gather."""
     i = lax.axis_index(axis_name) if axis_name is not None else 0
     page_size, max_pages = k_pool.shape[1], block_table.shape[1]

@@ -112,7 +112,7 @@ class AttentionPlanConfig:
     # simulated step cost, so it is part of the plan-cache key.
     comm_overlap: str = "overlap"
     paged: bool = False  # decode reads/writes a page pool through a block table
-    # decode kernel variant: "auto" -> "native" (the split-K Pallas kernel
+    # decode kernel variant: "auto" -> "native" (the paged Pallas kernel
     # reading the block table in-kernel, kernels/paged_decode.py) for the
     # paged cache wherever Pallas runs (TPU / REPRO_KERNELS=pallas), the
     # gather/band reference elsewhere; "native"/"gather" force either.
@@ -157,7 +157,7 @@ class AttentionPlanConfig:
 
 
 def _resolve_decode_kernel(kernel: Optional[str], paged: bool) -> str:
-    """"auto" -> the split-K native kernel for the paged cache (the gather
+    """"auto" -> the native paged kernel for the paged cache (the gather
     intermediate is exactly what it exists to kill) wherever the backend
     policy actually runs Pallas (TPU, or REPRO_KERNELS=pallas correctness
     runs) — "auto" off-TPU keeps the fast XLA gather/band reference, same
@@ -675,7 +675,7 @@ def decode_attention_step(
     pages, not rows, carry the memory).
 
     ``decode_kernel`` (default from ``ctx``) picks the band/gather oracle or
-    the split-K native kernel; "auto" resolves paged -> native, dense -> band.
+    the native paged kernel; "auto" resolves paged -> native, dense -> band.
     """
     n = ctx.sp_size
     pos = jnp.asarray(pos, jnp.int32)
@@ -693,7 +693,7 @@ def decode_attention_step(
     dense_kernel = _resolve_decode_kernel(decode_kernel, paged=False)
     if n == 1:
         if dense_kernel == "native":
-            # one shared update + split-K decode call covers scalar AND
+            # one shared update + paged decode call covers scalar AND
             # vector pos (the kernel's grid is per-row, no vmap needed)
             k_cache, v_cache = sharded_cache_update(
                 k_cache, v_cache, k_new, v_new, pos, None, 1, layout=layout
@@ -883,7 +883,7 @@ def chunk_attention_step(
     ``decode_attention_step`` — it is the same banded partial + lse psum with
     a multi-row q, so chunked prefill reproduces one-shot prefill bit-for-bit
     on the reference backend.  Chunks always run the band/gather path; the
-    split-K native kernel stays single-token.  ``k_scale``/``v_scale``
+    native paged kernel stays single-token.  ``k_scale``/``v_scale``
     (paged) quantize the chunk on write and extend the return to a 5-tuple,
     exactly like ``decode_attention_step``."""
     n = ctx.sp_size

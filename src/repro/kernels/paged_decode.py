@@ -1,4 +1,4 @@
-"""Paged-native split-K flash-decode Pallas kernel.
+"""Paged-native flash-decode Pallas kernel.
 
 The gather-based paged decode (``core/decode_attention.py::paged_cache_gather``
 + the dense band kernel) materializes each slot's full ``[max_pages *
@@ -6,27 +6,36 @@ page_size]`` local view from the physical page pool every tick, so decode HBM
 traffic scales with *virtual capacity*, not with how deep any request actually
 is.  This kernel reads the page pool **in place**:
 
-  * the int32 block table and the per-slot position vector are
-    **scalar-prefetched** (``pltpu.PrefetchScalarGridSpec``) so the BlockSpec
-    index maps resolve logical page -> physical page before each grid step's
-    DMA — the pool is indexed directly, no gathered intermediate ever exists;
-  * the grid is ``(batch, split, pages_per_split)`` — **split-K over pages**:
-    each split owns a contiguous run of a slot's logical pages and produces a
-    partial ``(o, lse)`` carried in VMEM scratch (online softmax over its
-    pages); splits combine outside the kernel with a numerically-stable LSE
-    reduce (:func:`combine_split_partials`).  Mixed-depth slot pools therefore
-    fill the grid with many small independent partials instead of serializing
-    every row behind the deepest one;
-  * pages a slot never allocated (block table ``-1``), pages past the row's
-    depth, and pages a sliding window provably hides are skipped with
-    ``pl.when`` predication, and their index maps **clamp to the nearest
-    visible page** so the Pallas pipeline re-fetches nothing (consecutive
-    equal block indices elide the DMA): HBM bytes/token follow depth;
-  * the **partial last page** of a depth not divisible by ``page_size`` is
-    masked inside the page by the position band (global position ``<= pos``),
-    so the split's lse counts exactly the live tail — the combine then weighs
-    it correctly against full pages (asserted exact-vs-oracle in
-    tests/test_paged_decode.py).
+  * the grid is **one step per slot**, ``(batch,)``.  The int32 block table,
+    the per-slot positions and ``kv_offset`` are **scalar-prefetched** into
+    SMEM; from them the step works out the slot's visible logical pages
+    ``[lp_lo, lp_hi]`` (depth, shard stride and sliding window) and runs a
+    ``lax.fori_loop`` over exactly those pages, nothing past them;
+  * the pool stays in HBM (``memory_space=pltpu.HBM``).  The loop walks the
+    visible pages in **blocks of P pages** (``P = max(1, 256 // page_size)``,
+    about 256 tokens per block: 16 pages of the 16-token serving pool, 2 of
+    the dense 128-token view).  Each page of a block is fetched with its own
+    ``make_async_copy`` into a double-buffered VMEM block ``[2, P*page_size,
+    Hkv, D]``; block j+1's copies are started before block j is computed,
+    and the last step of a slot starts the next slot's first block, so the
+    DMA engine is busy across slot boundaries too (grid axis ``arbitrary``).
+    Quantized pools fetch each page's ``[page_size, Hkv]`` scale tile through
+    the same page id into their own buffers and dequantize in VMEM;
+  * pages past the depth, pages the window hides and unallocated pages
+    (block table ``-1``) are neither copied nor computed: a free slot costs
+    one grid step and no DMA, and HBM bytes follow depth;
+  * online softmax in f32 over the blocks, per-kv-head GQA dots with f32
+    accumulation; the band predicate (global position ``<= pos`` and ``>=``
+    the window's start) on every column masks the partial last page and the
+    window tail, and a column mask drops the pages of a block that were not
+    copied.  The step writes the final ``(o, lse)``; a row with nothing
+    visible gets ``(0, NEG_INF)`` exactly, as the cross-shard psum combine
+    expects.
+
+There is no split axis over a slot's pages: the TPU v5e has one TensorCore
+and runs the grid in sequence, so splitting a slot buys no parallelism and
+only adds grid steps, f32 partials written to HBM and a combine read back.
+Cost per call is one step per slot plus one loop iteration per visible block.
 
 Geometry matches ``core/decode_attention.py`` verbatim: local slot ``j`` of a
 shard holds global position ``kv_offset + stride_kv * j`` (striped:
@@ -57,23 +66,23 @@ from repro.kernels.ref import BAND_INF, NEG_INF
 
 __all__ = [
     "paged_flash_decode",
-    "combine_split_partials",
-    "default_num_splits",
+    "pages_per_block",
     "dense_chunk_for",
 ]
 
-# default logical pages each split-K partial covers; small enough that a few
-# allocated pages already spread over several grid cells, big enough that the
-# per-split finalize/combine overhead stays negligible
-DEFAULT_PAGES_PER_SPLIT = 4
+# tokens one DMA block covers: enough pages per block that the copies stream
+# and the loop's fixed cost is spread, small enough that two K and two V
+# blocks (plus their f32 working copies) sit well inside scoped VMEM
+BLOCK_TOKENS = 256
 
 # candidate chunk sizes (local positions) for viewing a DENSE cache row as an
 # implicit page run; the largest divisor of m wins, capped MXU-friendly
 _DENSE_CHUNKS = (128, 64, 32, 16, 8, 4, 2, 1)
 
 
-def default_num_splits(max_pages: int) -> int:
-    return max(1, -(-max_pages // DEFAULT_PAGES_PER_SPLIT))
+def pages_per_block(page_size: int) -> int:
+    """Pages the kernel fetches and computes per loop iteration."""
+    return max(1, BLOCK_TOKENS // page_size)
 
 
 def dense_chunk_for(m: int) -> int:
@@ -83,27 +92,15 @@ def dense_chunk_for(m: int) -> int:
     return next(c for c in _DENSE_CHUNKS if c <= m and m % c == 0)
 
 
-def combine_split_partials(
-    o_parts: jnp.ndarray,  # [B, S, H, D] fp32 per-split partial outputs
-    lse_parts: jnp.ndarray,  # [B, S, H] fp32 per-split lse (NEG_INF = empty)
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Numerically-stable LSE reduce over the split axis -> ([B,1,H,D] fp32,
-    [B,H,1] fp32), the same (o, lse) contract the banded partial returns.
-
-    Empty splits (lse == NEG_INF) must contribute weight 0 even when EVERY
-    split is empty (then m == NEG_INF and exp(lse - m) would be 1): the
-    nonempty mask guards that, and a fully-hidden row combines to the exact
-    empty-band result (o = 0, lse = NEG_INF) the psum combine expects.
-    """
-    m = jnp.maximum(jnp.max(lse_parts, axis=1), NEG_INF)  # [B, H]
-    nonempty = lse_parts > NEG_INF / 2
-    w = jnp.where(nonempty, jnp.exp(lse_parts - m[:, None]), 0.0)  # [B, S, H]
-    den = jnp.sum(w, axis=1)  # [B, H]
-    num = jnp.einsum("bsh,bshd->bhd", w, o_parts)
-    den_safe = jnp.where(den > 0, den, 1.0)
-    o = num / den_safe[..., None]
-    lse = jnp.where(den > 0, m + jnp.log(den_safe), NEG_INF)
-    return o[:, None], lse[..., None]  # [B,1,H,D], [B,H,1]
+def _visible_pages(pos_b, kv_off, *, stride_kv, page_size, max_pages, hi):
+    """(first, last, window start) of a slot's visible logical pages; the
+    range is empty (last < first) when no local slot is visible."""
+    win_lo = jnp.maximum(pos_b - hi, 0)  # oldest visible global position
+    j_hi = (pos_b - kv_off) // stride_kv  # last local slot at or before pos
+    j_lo = jnp.maximum((win_lo - kv_off + stride_kv - 1) // stride_kv, 0)
+    lp_hi = jnp.minimum(j_hi // page_size, max_pages - 1)
+    lp_lo = j_lo // page_size
+    return lp_lo, lp_hi, win_lo
 
 
 def _decode_kernel(
@@ -111,96 +108,191 @@ def _decode_kernel(
     bt_ref,  # [B, max_pages] int32 block table; -1 = unallocated
     pos_ref,  # [B] int32 per-slot positions
     off_ref,  # [1] int32 kv_offset (may be traced from axis_index)
-    # blocks (VMEM)
+    # q block (VMEM) and the pools (HBM)
     q_ref,  # [1, H, D]
-    k_ref,  # [1, page_size, Hkv, D] one physical page
-    v_ref,
-    # quantized pools add two [1, page_size, Hkv] fp32 scale blocks here,
-    # then outputs o [1,1,H,D] / lse [1,1,H], then scratch acc/m/l
+    k_hbm,  # [num_pages, page_size, Hkv, D]
+    v_hbm,
+    # quantized pools add the two [num_pages, page_size, Hkv] fp32 scale
+    # tables here; then outputs o [1,H,D] / lse [1,1,H]; then scratch: the
+    # K/V blocks [2, P*page_size, Hkv, D] (+ scale blocks), the DMA sems
     *rest,
     scale: float,
     stride_kv: int,
     page_size: int,
     max_pages: int,
-    pages_per_split: int,
+    block_pages: int,
     hi: int,  # window - 1, or BAND_INF for no window
     group: int,  # H // Hkv (GQA)
     hkv: int,
     quantized: bool,
 ):
     if quantized:
-        ks_ref, vs_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+        (ks_hbm, vs_hbm, o_ref, lse_ref,
+         k_buf, v_buf, ks_buf, vs_buf, sems) = rest
     else:
-        ks_ref = vs_ref = None
-        o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
-    b, s, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    lp = s * pages_per_split + p  # logical page this grid step covers
-    pos_b = pos_ref[b]
+        o_ref, lse_ref, k_buf, v_buf, sems = rest
+        ks_hbm = vs_hbm = ks_buf = vs_buf = None
+    b, nb = pl.program_id(0), pl.num_programs(0)
     kv_off = off_ref[0]
-    page_lo = kv_off + stride_kv * (lp * page_size)  # first global pos in page
-    page_hi = kv_off + stride_kv * (lp * page_size + page_size - 1)
-    win_lo = jnp.maximum(pos_b - hi, 0)  # oldest visible global position
-    visible = (
-        (lp < max_pages)
-        & (bt_ref[b, jnp.minimum(lp, max_pages - 1)] >= 0)
-        & (page_lo <= pos_b)  # page starts at or before the row's depth
-        & (page_hi >= win_lo)  # page ends inside the sliding window
+    P, T = block_pages, block_pages * page_size
+    vis = functools.partial(
+        _visible_pages, stride_kv=stride_kv, page_size=page_size,
+        max_pages=max_pages, hi=hi,
     )
 
-    @pl.when(visible)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [H, D]
-        k = k_ref[0].astype(jnp.float32)  # [page_size, Hkv, D]
-        v = v_ref[0].astype(jnp.float32)
+    def n_blocks(lp_lo, lp_hi):
+        return jnp.maximum(lp_hi - lp_lo + P, 0) // P
+
+    def page_copies(slot_b, lp, lp_hi, buf, i):
+        """(predicate, copies) of page i of a block: logical page lp of
+        slot_b into rows [i*page_size, (i+1)*page_size) of buffer buf."""
+        pid = bt_ref[slot_b, jnp.minimum(lp, max_pages - 1)]
+        ok = (lp <= lp_hi) & (pid >= 0)
+        pid = jnp.maximum(pid, 0)
+        rows = pl.ds(pl.multiple_of(i * page_size, page_size), page_size)
+        cps = [
+            pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, rows], sems.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, rows], sems.at[1, buf]),
+        ]
         if quantized:
-            # dequantize IN VMEM, right after the page's DMA: the scale tile
-            # rode along as an extra prefetched operand through the same
-            # clamped index map, so HBM moved 1-byte elements + one fp32
-            # scale per (token, kv-head) instead of fp32 K/V
-            k = k * ks_ref[0][:, :, None].astype(jnp.float32)
-            v = v * vs_ref[0][:, :, None].astype(jnp.float32)
+            cps += [
+                pltpu.make_async_copy(ks_hbm.at[pid], ks_buf.at[buf, i], sems.at[0, buf]),
+                pltpu.make_async_copy(vs_hbm.at[pid], vs_buf.at[buf, i], sems.at[1, buf]),
+            ]
+        return ok, rows, cps
+
+    def start_block(slot_b, lp0, lp_hi, buf):
+        def one(i, _):
+            ok, _, cps = page_copies(slot_b, lp0 + i, lp_hi, buf, i)
+
+            @pl.when(ok)
+            def _():
+                for cp in cps:
+                    cp.start()
+
+            return 0
+
+        jax.lax.fori_loop(0, P, one, 0)
+
+    def wait_block(lp0, lp_hi, buf):
+        """Wait for the block's copies; returns the [1, T] column mask of the
+        pages that were copied.  A page not copied has its V rows (and V
+        scales) zeroed: its columns carry weight 0, and 0 * stale VMEM must
+        not turn into NaN."""
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1) // page_size
+
+        def one(i, copied):
+            ok, rows, cps = page_copies(b, lp0 + i, lp_hi, buf, i)
+
+            @pl.when(ok)
+            def _():
+                for cp in cps:
+                    cp.wait()
+
+            @pl.when(jnp.logical_not(ok))
+            def _():
+                v_buf[buf, rows] = jnp.zeros((page_size,) + v_buf.shape[2:], v_buf.dtype)
+                if quantized:
+                    vs_buf[buf, i] = jnp.zeros(vs_buf.shape[2:], vs_buf.dtype)
+
+            return jnp.where(cols == i, ok.astype(jnp.int32), copied)
+
+        return jax.lax.fori_loop(0, P, one, jnp.zeros((1, T), jnp.int32)) > 0
+
+    pos_b = pos_ref[b]
+    lp_lo, lp_hi, win_lo = vis(pos_b, kv_off)
+    nblk = n_blocks(lp_lo, lp_hi)
+
+    # the first block of slot 0 is started here; every later slot's first
+    # block was started by the step before it (below)
+    @pl.when((b == 0) & (nblk > 0))
+    def _():
+        start_block(b, lp_lo, lp_hi, 0)
+
+    q = q_ref[0].astype(jnp.float32)  # [H, D]
+    H, D = q.shape
+
+    def token_scales(rows):
+        """[P, 1, L] page rows of (token, kv-head) scales -> [T, Hkv]: each
+        page row repeated over its tokens, then lane (r*Hkv + h) of token r
+        picked into column h by a 0/1 matmul (exact at HIGHEST)."""
+        L = rows.shape[-1]
+        rep = jnp.broadcast_to(rows, (P, page_size, L)).reshape(T, L)
+        r = jax.lax.broadcasted_iota(jnp.int32, (T, L), 0) % page_size
+        lane = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1)
+        own = jnp.where(lane // hkv == r, rep, 0.0)  # token r's Hkv lanes
+        pick = (jax.lax.broadcasted_iota(jnp.int32, (L, hkv), 0) % hkv
+                == jax.lax.broadcasted_iota(jnp.int32, (L, hkv), 1))
+        return jax.lax.dot(own, pick.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+    def body(j, carry):
+        m_prev, l_prev, acc_prev = carry
+        buf = j % 2
+        lp0 = lp_lo + j * P
+
+        @pl.when(j + 1 < nblk)
+        def _():
+            start_block(b, lp0 + P, lp_hi, 1 - buf)
+
+        copied = wait_block(lp0, lp_hi, buf)
+        k = k_buf[buf].astype(jnp.float32)  # [T, Hkv, D]
+        v = v_buf[buf].astype(jnp.float32)
+        if quantized:
+            # dequantize IN VMEM, right after the block's DMAs: the scale
+            # tiles rode the same page ids, so HBM moved 1-byte elements + one
+            # fp32 scale per (token, kv-head) instead of fp32 K/V
+            k = k * token_scales(ks_buf[buf])[:, :, None]
+            v = v * token_scales(vs_buf[buf])[:, :, None]
         s_rows = []
-        for hk in range(hkv):  # GQA: per-kv-head [group, page_size] scores
+        for hk in range(hkv):  # GQA: per-kv-head [group, T] scores
             s_rows.append(jax.lax.dot_general(
                 q[hk * group : (hk + 1) * group], k[:, hk, :],
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
             ))
-        sc = jnp.concatenate(s_rows, axis=0) * scale  # [H, page_size]
+        sc = jnp.concatenate(s_rows, axis=0) * scale  # [H, T]
         cols = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        gpos = page_lo + stride_kv * cols  # global position per column
+        gpos = kv_off + stride_kv * (lp0 * page_size + cols)  # global position
         # the band masks the partial last page (columns past pos) AND any
-        # in-page window tail — exactly the dense band kernel's predicate
-        mask = (gpos <= pos_b) & (gpos >= win_lo)
-        m_prev = m_ref[...]
+        # in-block window tail — exactly the dense band kernel's predicate;
+        # ``copied`` drops pages past the range or never allocated
+        mask = (gpos <= pos_b) & (gpos >= win_lo) & copied
         m_cur = jnp.max(jnp.where(mask, sc, NEG_INF), axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         pw = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pw, axis=1, keepdims=True)
+        l_new = l_prev * alpha + jnp.sum(pw, axis=1, keepdims=True)
         o_rows = []
         for hk in range(hkv):
             o_rows.append(jax.lax.dot(
                 pw[hk * group : (hk + 1) * group], v[:, hk, :],
                 preferred_element_type=jnp.float32,
             ))
-        acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(o_rows, axis=0)
-        m_ref[...] = m_new
+        acc_new = acc_prev * alpha + jnp.concatenate(o_rows, axis=0)
+        return m_new, l_new, acc_new
 
-    @pl.when(p == pages_per_split - 1)
-    def _finalize():
-        l = l_ref[...]
-        l_safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = acc_ref[...] / l_safe
-        lse_ref[0, 0, 0] = jnp.where(
-            l[:, 0] > 0, m_ref[:, 0] + jnp.log(l_safe[:, 0]), NEG_INF
-        )
+    init = (
+        jnp.full((H, 1), NEG_INF, jnp.float32),
+        jnp.zeros((H, 1), jnp.float32),
+        jnp.zeros((H, D), jnp.float32),
+    )
+    m, l, acc = jax.lax.fori_loop(0, nblk, body, init)
+
+    # every copy of this slot has been waited for: start the next slot's
+    # first block so its DMAs overlap this step's epilogue and the next
+    # step's prologue
+    @pl.when(b + 1 < nb)
+    def _():
+        lo1, hi1, _ = vis(pos_ref[b + 1], kv_off)
+
+        @pl.when(n_blocks(lo1, hi1) > 0)
+        def _():
+            start_block(b + 1, lo1, hi1, 0)
+
+    l_safe = jnp.where(l > 0, l, 1.0)
+    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    lse_ref[0, 0] = jnp.where(l[:, 0] > 0, m[:, 0] + jnp.log(l_safe[:, 0]), NEG_INF)
 
 
 def paged_flash_decode(
@@ -214,7 +306,6 @@ def paged_flash_decode(
     stride_kv: int,
     window: Optional[int] = None,
     scale: Optional[float] = None,
-    num_splits: Optional[int] = None,
     interpret: Optional[bool] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [num_pages, page_size, Hkv] f32
     v_scale: Optional[jnp.ndarray] = None,
@@ -224,8 +315,8 @@ def paged_flash_decode(
     gather path's banded partial, ready for the cross-shard psum combine.
 
     ``k_scale``/``v_scale`` mark a quantized pool (int8 / fp8 elements):
-    each page's scale tile is fetched through the same clamped index map and
-    K/V are dequantized in VMEM right after the DMA."""
+    each page's scale tile is fetched through the same page id and K/V are
+    dequantized in VMEM right after the DMA."""
     B, _, H, D = q.shape
     num_pages, page_size, hkv, _ = k_pool.shape
     max_pages = block_table.shape[1]
@@ -238,80 +329,63 @@ def paged_flash_decode(
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     off = jnp.reshape(jnp.asarray(kv_offset, jnp.int32), (1,))
     bt = jnp.asarray(block_table, jnp.int32)
-    if num_splits is None:
-        num_splits = default_num_splits(max_pages)
-    num_splits = max(1, min(int(num_splits), max_pages))
-    pages_per_split = -(-max_pages // num_splits)
+    P = pages_per_block(page_size)
+    T = P * page_size
     interpret = resolve_interpret(interpret)
 
-    def kv_index_map(b, s, p, bt_ref, pos_ref, off_ref):
-        # clamp invisible steps to the nearest VISIBLE logical page so runs of
-        # skipped steps keep the block index constant and the pipeline elides
-        # their DMAs (depth-proportional HBM traffic, not capacity)
-        lp = s * pages_per_split + p
-        pos_b, kv_off = pos_ref[b], off_ref[0]
-        lp_hi = (pos_b - kv_off) // (stride_kv * page_size)  # last visible
-        win_lo = jnp.maximum(pos_b - hi, 0)
-        j_lo = (win_lo - kv_off + stride_kv - 1) // stride_kv
-        lp_lo = jnp.maximum(j_lo, 0) // page_size  # first visible
-        lp_hi = jnp.clip(lp_hi, 0, max_pages - 1)
-        lp_lo = jnp.clip(lp_lo, 0, lp_hi)
-        lp_eff = jnp.clip(lp, lp_lo, lp_hi)
-        return (jnp.maximum(bt_ref[b, lp_eff], 0), 0, 0, 0)
-
-    def scale_index_map(b, s, p, bt_ref, pos_ref, off_ref):
-        # the scale tile rides the pool's physical-page resolution verbatim
-        return kv_index_map(b, s, p, bt_ref, pos_ref, off_ref)[:3]
-
     quantized = k_scale is not None
-    in_specs = [
-        pl.BlockSpec((1, H, D), lambda b, s, p, *_: (b, 0, 0)),
-        pl.BlockSpec((1, page_size, hkv, D), kv_index_map),
-        pl.BlockSpec((1, page_size, hkv, D), kv_index_map),
-    ]
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)), hbm, hbm]
     operands = [bt, pos, off, q[:, 0], k_pool, v_pool]
+    scratch = [
+        pltpu.VMEM((2, T, hkv, D), k_pool.dtype),
+        pltpu.VMEM((2, T, hkv, v_pool.shape[-1]), v_pool.dtype),
+    ]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, page_size, hkv), scale_index_map),
-            pl.BlockSpec((1, page_size, hkv), scale_index_map),
-        ]
-        operands += [k_scale, v_scale]
+        # a DMA may only slice whole 128-lane rows, so each page's
+        # [page_size, Hkv] scale tile travels as one lane-dense row
+        lanes = -(-page_size * hkv // 128) * 128
+
+        def rows(s):
+            s = s.astype(jnp.float32).reshape(num_pages, page_size * hkv)
+            s = jnp.pad(s, ((0, 0), (0, lanes - page_size * hkv)))
+            return s.reshape(num_pages, 1, lanes)
+
+        in_specs += [hbm, hbm]
+        operands += [rows(k_scale), rows(v_scale)]
+        scratch += [pltpu.VMEM((2, P, 1, lanes), jnp.float32)] * 2
+    scratch.append(pltpu.SemaphoreType.DMA((2, 2)))  # [K|V, buffer]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, num_splits, pages_per_split),
+        grid=(B,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, H, D), lambda b, s, p, *_: (b, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, H), lambda b, s, p, *_: (b, s, 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, 1, H), lambda b, *_: (b, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _decode_kernel,
         scale=float(scale), stride_kv=stride_kv, page_size=page_size,
-        max_pages=max_pages, pages_per_split=pages_per_split, hi=hi,
+        max_pages=max_pages, block_pages=P, hi=hi,
         group=group, hkv=hkv, quantized=quantized,
     )
     like = tuple(operands)
-    o_parts, lse_parts = pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            vma_struct((B, num_splits, H, D), jnp.float32, *like),
-            vma_struct((B, num_splits, 1, H), jnp.float32, *like),
+            vma_struct((B, H, D), q.dtype, *like),
+            vma_struct((B, 1, H), jnp.float32, *like),
         ],
         interpret=interpret,
         compiler_params=None
         if interpret
-        else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        # the next slot's first block is started inside this step: the
+        # steps must run in order on one core
+        else pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         name="paged_flash_decode",
     )(*operands)
-    o, lse = combine_split_partials(o_parts, lse_parts[:, :, 0])
-    return o.astype(q.dtype), lse
+    return o[:, None], jnp.swapaxes(lse, 1, 2)

@@ -66,8 +66,8 @@ def main():
                     help="physical pool size in pages (paged mode)")
     ap.add_argument("--decode-kernel", default="auto",
                     choices=("auto", "native", "gather"),
-                    help="flash-decode variant: auto (paged -> split-K "
-                         "native kernel), native, or the gather oracle")
+                    help="flash-decode variant: auto (paged -> the native "
+                         "paged kernel), native, or the gather oracle")
     ap.add_argument("--kv-dtype", default="fp", choices=("fp", "int8", "fp8"),
                     help="paged-pool storage: fp keeps cache_dtype; int8/fp8 "
                          "store quantized pages + per-(token, kv-head) f32 "

@@ -432,7 +432,7 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, ctx: ParallelCtx):
     A paged cache's block table ``cache["bt"]`` is threaded to the decode
     backend VERBATIM (layer-shared device operand): ``ctx.decode_kernel``
     picks whether it drives a page gather or is scalar-prefetched into the
-    native split-K kernel (kernels/paged_decode.py)."""
+    native paged kernel (kernels/paged_decode.py)."""
     pos = cache["pos"]
     bt = cache.get("bt")  # paged K/V: block table, shared by every layer
     x = jnp.take(params["embed"], tokens, axis=0)

@@ -37,7 +37,7 @@ class ParallelCtx:
     # (Figure 6) through the on-disk plan cache instead of the sqrt-n heuristic
     plan_cache_dir: Optional[str] = None  # None -> dispatch's default cache dir
     decode_kernel: str = "auto"  # flash-decode variant: auto (paged -> the
-    # split-K native kernel where Pallas runs, else the gather/band
+    # native paged kernel where Pallas runs, else the gather/band
     # reference) | native | gather
     # --- other knobs ---
     remat: bool = True

@@ -136,7 +136,7 @@ class ServeEngine:
         self.cfg = cfg
         self.ctx = ctx or ParallelCtx()
         # flash-decode kernel variant: "auto" serves the paged cache with the
-        # split-K native kernel (block table read in-kernel) wherever Pallas
+        # native paged kernel (block table read in-kernel) wherever Pallas
         # runs, the gather/band reference elsewhere; "native"/"gather" force
         if serve.decode_kernel != "auto":
             self.ctx = dataclasses.replace(self.ctx, decode_kernel=serve.decode_kernel)
@@ -187,7 +187,7 @@ class ServeEngine:
         self._quantized = serve.kv_dtype != "fp"
         self.dequant_fallbacks = 0  # quantized ticks served by the gather ref
         # the decode kernel "auto" resolved to on this platform: "native"
-        # (split-K Pallas), "gather" (paged reference) or "band" (dense)
+        # (paged Pallas kernel), "gather" (paged reference) or "band" (dense)
         self.decode_kernel = dispatch._resolve_decode_kernel(
             getattr(self.ctx, "decode_kernel", "auto"), paged=serve.paged
         )

@@ -1092,7 +1092,7 @@ def check_paged_serve():
     sharing a 32-token prefix must allocate strictly fewer pages than an
     unshared pair while still matching the dense engine exactly.
 
-    A second paged run forces ``decode_kernel="native"`` — the split-K kernel
+    A second paged run forces ``decode_kernel="native"`` — the paged kernel
     (kernels/paged_decode.py: block table read in-kernel, no gather
     intermediate; interpret-mode Pallas on these CPU devices) — and must
     produce the same tokens, so native == gather == dense on the live serve
@@ -1135,7 +1135,7 @@ def check_paged_serve():
     assert paged_toks == dense_toks, (paged_toks, dense_toks)
     assert paged_eng.decode_trace_count == 1, paged_eng.decode_trace_count
     assert paged_eng.allocator.pages_in_use == 0  # every retirement freed
-    # the NATIVE split-K kernel (forced; interpret-mode Pallas on CPU) must
+    # the NATIVE paged kernel (forced; interpret-mode Pallas on CPU) must
     # reproduce the trace token-for-token on the (2, 4) mesh
     native_toks, _ = run_engine(
         prompts, arrivals, paged=True, page_size=4, decode_kernel="native"

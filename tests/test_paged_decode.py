@@ -1,4 +1,4 @@
-"""Paged-native split-K flash-decode kernel vs the gather-then-dense oracle.
+"""Paged-native flash-decode kernel vs the gather-then-dense oracle.
 
 The native kernel (kernels/paged_decode.py) must agree with the gather path
 (page-gather + band kernel) to combine-order fp tolerance for arbitrary
@@ -31,7 +31,9 @@ POISON = 1e4  # any leak of a masked/unallocated position is unmissable
 # native-vs-oracle tolerance per storage mode: both paths dequantize the SAME
 # stored values, so quantization noise cancels and only combine-order fp error
 # remains; quantized modes get a little headroom for the extra scale multiply
-_TOLS = {"fp": (2e-5, 1e-5), "int8": (5e-5, 2e-5), "fp8": (5e-5, 2e-5)}
+_TOLS = {
+    "fp": (2e-5, 1e-5), "bf16": (2e-5, 1e-5), "int8": (5e-5, 2e-5), "fp8": (5e-5, 2e-5),
+}
 
 
 def _build_pool(rng, depths, page_size, max_pages, extra_pages=0, kv_dtype="fp"):
@@ -132,7 +134,7 @@ def test_native_matches_gather_oracle(
 
 
 # --------------------------------------------------------------------------
-# partial last page: the in-page tail mask is where split-K silently breaks
+# partial last page: the in-page tail mask is where a page walk silently breaks
 # --------------------------------------------------------------------------
 
 
@@ -169,8 +171,8 @@ def test_partial_last_page_exact_against_truncated_oracle():
 
 def test_empty_shard_returns_exact_empty_band():
     """A shard holding nothing visible must return o = 0, lse = NEG_INF
-    exactly (the psum combine depends on it); all-empty splits must not
-    resurrect with weight exp(NEG_INF - NEG_INF) = 1."""
+    exactly (the psum combine depends on it): a row that visits no page
+    must not resurrect with weight exp(NEG_INF - NEG_INF) = 1."""
     rng = np.random.default_rng(1)
     _, k_pool, v_pool, bt, _, _ = _build_pool(rng, [8], 4, 3)
     q = jnp.asarray(rng.normal(size=(1, 1, H, D)), jnp.float32)
@@ -182,12 +184,94 @@ def test_empty_shard_returns_exact_empty_band():
     np.testing.assert_array_equal(np.asarray(lse), np.float32(NEG_INF))
 
 
-def test_combine_split_partials_empty_guard():
-    o = jnp.zeros((1, 3, H, D), jnp.float32)
-    lse = jnp.full((1, 3, H), NEG_INF, jnp.float32)
-    oc, lc = pk.combine_split_partials(o, lse)
-    np.testing.assert_array_equal(np.asarray(oc), 0.0)
-    np.testing.assert_array_equal(np.asarray(lc), np.float32(NEG_INF))
+# --------------------------------------------------------------------------
+# the block walk: one grid step per slot, P pages per DMA block
+# --------------------------------------------------------------------------
+
+_PS = 16  # serving page size: P = pages_per_block(16) pages per block
+_P = pk.pages_per_block(_PS)
+
+
+def _shuffled_pool(rng, depths, max_pages, kv_dtype):
+    """Slots at the given LOCAL depths on physical pages drawn from a
+    permutation of the pool (two pages no table names); every unwritten
+    position poisoned.  A depth of 0 is a free slot: its table row is all
+    -1.  Returns the pool (bf16 / quantized storage as asked), its scale
+    tables or None, the block table, and the dense f32 view the oracle reads
+    (the stored values, dequantized)."""
+    counts = [-(-d // _PS) for d in depths]
+    num_pages = sum(counts) + 2
+    phys = rng.permutation(num_pages)
+    bt = np.full((len(depths), max_pages), -1, np.int32)
+    used = np.zeros((num_pages, _PS), bool)
+    taken = 0
+    for b, (d, c) in enumerate(zip(depths, counts)):
+        bt[b, :c] = phys[taken : taken + c]
+        taken += c
+        flat = np.arange(d)
+        used[bt[b, flat // _PS], flat % _PS] = True
+    kv = rng.normal(size=(2, num_pages, _PS, HKV, D)).astype(np.float32)
+    kv = np.where(used[None, ..., None, None], kv, POISON)
+    scales = (None, None)
+    if kv_dtype == "bf16":
+        pools = tuple(jnp.asarray(x, jnp.bfloat16) for x in kv)
+        stored = tuple(np.asarray(x.astype(jnp.float32)) for x in pools)
+    else:
+        qs = [kv_quant.quantize(jnp.asarray(x), kv_dtype) for x in kv]
+        pools = tuple(c for c, _ in qs)
+        scales = tuple(sc for _, sc in qs)
+        stored = tuple(np.asarray(kv_quant.dequantize(c, sc)) for c, sc in qs)
+    dense = []
+    for x in stored:
+        view = np.zeros((len(depths), max_pages * _PS, HKV, D), np.float32)
+        for b, d in enumerate(depths):
+            flat = np.arange(d)
+            view[b, :d] = x[bt[b, flat // _PS], flat % _PS]
+        dense.append(view)
+    return pools, scales, jnp.asarray(bt), dense
+
+
+# (local depths, stride_kv, kv_offset, window); every case holds a free
+# slot (depth 0) first, whose row must come back exactly (0, NEG_INF)
+_WALK_CASES = {
+    # 1 token, one page, one whole block, one token into the second block,
+    # and a depth ending mid-page in the second block
+    "mixed-depths": ([0, 1, _PS, _P * _PS, _P * _PS + 1, _P * _PS + _PS + 5], 1, 0, None),
+    # striped shard 1 of 2: local slot j holds global position 1 + 2j
+    "stride2-offset": ([0, 3, _PS + 2, _P * _PS + 7], 2, 1, None),
+    # the window's first visible position falls mid-page, mid-block
+    "window-in-block": ([0, _PS - 3, 2 * _P * _PS + 9, _P * _PS + _PS + 5], 1, 0, 3 * _PS + 7),
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_block_walk_matches_gather_oracle(case, kv_dtype):
+    """Shuffled physical pages, mixed depths around the page and block
+    boundaries, shard stride and offset, and a window starting inside a
+    block: the block walk must read exactly the visible positions."""
+    depths, stride, kv_off, window = _WALK_CASES[case]
+    rng = np.random.default_rng(7)
+    max_pages = -(-max(depths) // _PS) + 1
+    (k_pool, v_pool), (k_scale, v_scale), bt, (dense_k, dense_v) = _shuffled_pool(
+        rng, depths, max_pages, kv_dtype
+    )
+    q = jnp.asarray(rng.normal(size=(len(depths), 1, H, D)), jnp.float32)
+    # the free slot sits at position 0 with an all -1 table row, as the
+    # engine leaves it; every other row attends to its last written slot
+    pos = np.asarray([kv_off + stride * max(d - 1, 0) for d in depths], np.int32)
+    o_n, lse_n = pk.paged_flash_decode(
+        q, k_pool, v_pool, bt, jnp.asarray(pos), kv_off,
+        stride_kv=stride, window=window, k_scale=k_scale, v_scale=v_scale,
+    )
+    np.testing.assert_array_equal(np.asarray(o_n[0]), 0.0)
+    np.testing.assert_array_equal(np.asarray(lse_n[0]), np.float32(NEG_INF))
+    o_g, lse_g = _oracle_partial(q, dense_k, dense_v, pos, kv_off, stride, window)
+    atol, rtol = _TOLS[kv_dtype]
+    np.testing.assert_allclose(np.asarray(o_n[1:]), np.asarray(o_g[1:]), atol=atol, rtol=rtol)
+    np.testing.assert_allclose(
+        np.asarray(lse_n[1:]), np.asarray(lse_g[1:]), atol=atol, rtol=rtol
+    )
 
 
 # --------------------------------------------------------------------------
@@ -237,7 +321,7 @@ def test_cow_shared_page_decode():
 
 
 # --------------------------------------------------------------------------
-# dense cache as one implicit page run (split-K for the dense engine too)
+# dense cache as one implicit page run (the paged kernel for the dense engine too)
 # --------------------------------------------------------------------------
 
 

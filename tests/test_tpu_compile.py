@@ -106,6 +106,45 @@ def test_paged_decode_compiles_for_v5e(one_chip, kv_dtype):
     _compile(f, q, pool, pool, bt, pos, scale, scale)
 
 
+_CELL_POOLS = {
+    # the serving cell: 64 slots, 16-token pages, max_seq 4096, 8,192 pages
+    "bf16": (64, 16, 256, 8192, jnp.bfloat16, 1, None),
+    "int8": (64, 16, 256, 8192, jnp.int8, 1, None),
+    "fp8": (64, 16, 256, 8192, jnp.float8_e4m3fn, 1, None),
+    # a dense [64, 4096] cache viewed as 128-token pages (2 pages a block)
+    "dense-view": (64, 128, 32, 64 * 32, jnp.bfloat16, 1, None),
+    # striped shard of a 2-way sequence split under a sliding window
+    "stride2-window": (64, 16, 256, 8192, jnp.bfloat16, 2, 1024),
+}
+
+
+@pytest.mark.parametrize("pool", sorted(_CELL_POOLS))
+def test_paged_decode_compiles_at_cell_shapes(one_chip, pool):
+    """The block walk at the chat cell's pool, plus the dense view and a
+    sharded windowed geometry: VMEM for the double-buffered blocks and
+    Mosaic's DMA slicing are checked here, before the chip."""
+    slots, page, max_pages, num_pages, elem, stride, window = _CELL_POOLS[pool]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    quant = elem in (jnp.int8, jnp.float8_e4m3fn)
+    q = sds((slots, 1, H, D), jnp.bfloat16)
+    kv = sds((num_pages, page, HKV, D), elem)
+    scale = sds((num_pages, page, HKV), jnp.float32)
+    bt = sds((slots, max_pages), jnp.int32)
+    pos = sds((slots,), jnp.int32)
+    off = sds((), jnp.int32)  # traced, as under shard_map
+
+    def f(q, k, v, bt, pos, off, ks, vs):
+        return pk.paged_flash_decode(
+            q, k, v, bt, pos, off, stride_kv=stride, window=window,
+            interpret=False, k_scale=ks if quant else None,
+            v_scale=vs if quant else None,
+        )
+    _compile(f, q, kv, kv, bt, pos, off, scale, scale)
+
+
 @pytest.mark.parametrize("groups", [1, 2])
 def test_ssd_scan_compiles_for_v5e(one_chip, groups):
     """The Mamba-2 SSD scan at 64-token chunks, head dim 64, state 128."""
