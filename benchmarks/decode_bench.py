@@ -161,7 +161,7 @@ def bench_op_level(reps: int = 30, seed: int = 0):
                 fn = jax.jit(
                     lambda q, kn, vn, kp, vp, pos, bt, _k=kernel:
                     dispatch.decode_attention_step(
-                        q, kn, vn, kp, vp, pos, ctx,
+                        q, kn, vn, kp[None], vp[None], pos, ctx, layer=0,
                         block_table=bt, decode_kernel=_k,
                     )
                 )
@@ -185,9 +185,9 @@ def bench_op_level(reps: int = 30, seed: int = 0):
                 fn_q = jax.jit(
                     lambda q, kn, vn, kp, vp, pos, bt, ks, vs, _k=kernel:
                     dispatch.decode_attention_step(
-                        q, kn, vn, kp, vp, pos, ctx,
+                        q, kn, vn, kp[None], vp[None], pos, ctx, layer=0,
                         block_table=bt, decode_kernel=_k,
-                        k_scale=ks, v_scale=vs,
+                        k_scale=ks[None], v_scale=vs[None],
                     )
                 )
                 ops_q = (q, k_new, v_new, qk_pool, qv_pool, pos, bt,

@@ -70,6 +70,7 @@ LOGIT_REL_BOUND = 0.05
 # |o|: bf16 probabilities and bf16 output rounding are 2**-9 each
 DECODE_DEPTHS = (3, 160, 1056, 2080)
 DECODE_PAGE_SIZE = 16  # the engine's default at one device
+DECODE_LAYER = 2  # the kernel reads this layer of a stacked pool (layers 0-1 poison)
 DECODE_O_REL_BOUND = 2e-2
 DECODE_LSE_BOUND = 2e-2  # absolute; bf16 inputs, f32 scores and sums
 POISON = 1e4  # unused page slots: a leaked read dominates the softmax
@@ -145,7 +146,9 @@ def train_depth(name):
 def decode_kernel_phase(cfg, *, depths, page_size, max_seq, seed):
     """``paged_flash_decode`` alone at the model's heads, bf16 and int8
     pools, physical pages shuffled and every unwritten slot poisoned,
-    against a float64 numpy softmax over the same stored K/V.  Returns
+    against a float64 numpy softmax over the same stored K/V.  The pages
+    sit at layer ``DECODE_LAYER`` of a stacked pool whose layers below it
+    are all poison, so a read of the wrong layer cannot pass.  Returns
     {kv_dtype: (o_rel_err, lse_err)}, the worst slot of each."""
     import jax.numpy as jnp
     import numpy as np
@@ -191,8 +194,16 @@ def decode_kernel_phase(cfg, *, depths, page_size, max_seq, seed):
             k_pool, v_pool = (jnp.where(poison[..., None], jnp.int8(127), c)
                               for c in (k_pool, v_pool))
             scales = {"k_scale": ks, "v_scale": vs}
+
+        def stack(x, fill):  # the pages at DECODE_LAYER, poison below it
+            below = jnp.full((DECODE_LAYER,) + x.shape, fill, x.dtype)
+            return jnp.concatenate([below, x[None]])
+
+        fill = POISON if kv_dtype == "fp" else 127
+        k_pool, v_pool = stack(k_pool, fill), stack(v_pool, fill)
+        scales = {name: stack(s, POISON) for name, s in scales.items()}
         o, lse = pk.paged_flash_decode(q, k_pool, v_pool, jnp.asarray(bt), pos, 0,
-                                       stride_kv=1, **scales)
+                                       DECODE_LAYER, stride_kv=1, **scales)
         o = np.asarray(o.astype(jnp.float32), np.float64)[:, 0]
         lse = np.asarray(lse, np.float64)[..., 0]
         o_err = lse_err = 0.0
@@ -473,8 +484,9 @@ def run_one_chip(seed, clock):
     errs = decode_kernel_phase(cfg, depths=DECODE_DEPTHS, page_size=DECODE_PAGE_SIZE,
                                max_seq=MAX_SEQ, seed=seed)
     for kv_dtype, (o_err, lse_err) in errs.items():
-        print(f"decode kernel: {kv_dtype} pool, H={cfg.num_heads} Hkv="
-              f"{cfg.num_kv_heads} D={cfg.hd}, depths {DECODE_DEPTHS}: o max abs "
+        print(f"decode kernel: {kv_dtype} pool at layer {DECODE_LAYER} of a stack, "
+              f"H={cfg.num_heads} Hkv={cfg.num_kv_heads} D={cfg.hd}, depths "
+              f"{DECODE_DEPTHS}: o max abs "
               f"err {o_err:.4g} of the slot's max |o| (bound {DECODE_O_REL_BOUND}), "
               f"lse {lse_err:.4g} (bound {DECODE_LSE_BOUND})")
         check(o_err <= DECODE_O_REL_BOUND and lse_err <= DECODE_LSE_BOUND,

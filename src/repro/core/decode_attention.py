@@ -32,6 +32,13 @@ Two cache layouts share the band math:
     into the same local-position order the dense slice has, so the kernel
     call — and therefore the numerics — are identical to the dense path.
 
+Every entry takes the caches STACKED over the model's layers (``[L, B, m,
+Hkv, D]`` / ``[L, num_pages, page_size, Hkv, D]``, scale tables likewise)
+plus the ``layer`` it serves: the model carries the whole stack through its
+layer loop, appends scatter at ``[layer, ...]`` in place and reads index
+``[layer, ...]`` directly, so no layer's slice of the cache is copied out
+and written back per step.
+
 Under a sliding window, shards whose whole local slice provably falls outside
 every row's window skip the kernel call entirely (``lax.cond``): the skip
 branch returns the exact empty-band result (o = 0, lse = NEG_INF), so the
@@ -84,36 +91,31 @@ def _owner_slot(pos, i, n: int, m: int, layout: str):
 
 
 def sharded_cache_update(
-    k_cache: jnp.ndarray,  # [B, m, Hkv, D] local slice
+    k_cache: jnp.ndarray,  # [L, B, m, Hkv, D] local slices, stacked over layers
     v_cache: jnp.ndarray,
     k_new: jnp.ndarray,  # [B, 1, Hkv, D] replicated across the axis
     v_new: jnp.ndarray,
     pos,  # int32 scalar or [B] vector: global position(s) being written
+    layer,  # int32: the layer written
     axis_name: Optional[str],
     n: int,
     layout: str = "striped",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Each batch row scatters its new token into its own slot of layer
+    ``layer``; rows this shard does not own, and rows past capacity
+    (retired slots still ticking), are dropped by the scatter."""
     i = lax.axis_index(axis_name) if axis_name is not None else 0
-    m = k_cache.shape[1]
-    pos = jnp.asarray(pos, jnp.int32)
-    if pos.ndim == 0:
-        is_owner, slot = _owner_slot(pos, i, n, m, layout)
-        k_upd = lax.dynamic_update_slice_in_dim(k_cache, k_new.astype(k_cache.dtype), slot, axis=1)
-        v_upd = lax.dynamic_update_slice_in_dim(v_cache, v_new.astype(v_cache.dtype), slot, axis=1)
-        k_cache = jnp.where(is_owner, k_upd, k_cache)
-        v_cache = jnp.where(is_owner, v_upd, v_cache)
-        return k_cache, v_cache
-    # per-slot positions: each batch row scatters into its own slot; rows past
-    # capacity (retired slots still ticking) are masked off rather than OOB
+    m = k_cache.shape[2]
+    B = k_new.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     is_owner, slot = _owner_slot(pos, i, n, m, layout)
     write = is_owner & (pos < n * m)
     slot = jnp.clip(slot, 0, m - 1)
-    b = jnp.arange(k_cache.shape[0])
+    # out-of-range batch index -> scatter drops the row entirely
+    b_idx = jnp.where(write, jnp.arange(B), B)
     out = []
     for cache, new in ((k_cache, k_new), (v_cache, v_new)):
-        cur = cache[b, slot]  # [B, Hkv, D]
-        val = jnp.where(write[:, None, None], new[:, 0].astype(cache.dtype), cur)
-        out.append(cache.at[b, slot].set(val))
+        out.append(cache.at[layer, b_idx, slot].set(new[:, 0].astype(cache.dtype), mode="drop"))
     return out[0], out[1]
 
 
@@ -227,9 +229,10 @@ def _native_enabled(kernel: str) -> bool:
 
 def sharded_cache_decode(
     q: jnp.ndarray,  # [B, 1, H, D] new token's query, replicated over the axis
-    k_cache: jnp.ndarray,  # [B, m, Hkv, D] local slice
+    k_cache: jnp.ndarray,  # [L, B, m, Hkv, D] local slices, stacked over layers
     v_cache: jnp.ndarray,
     pos,  # int32 scalar or [B] vector: current position(s); attends to <= pos
+    layer,  # int32: the layer read
     axis_name: Optional[str],
     n: int,
     *,
@@ -242,31 +245,31 @@ def sharded_cache_decode(
     """One decode step: partial attention per shard + lse-weighted psum.
 
     ``kernel="native"`` views each row's dense slice as ONE implicit page run
-    (reshape + identity block table) and runs the paged kernel — same
-    band math, no per-row vmap, each row walks only its visible pages.
+    (a free reshape of the stack + identity block table) and runs the paged
+    kernel at ``layer`` — same band math, no per-row vmap, each row walks
+    only its visible pages.
     """
     i = lax.axis_index(axis_name) if axis_name is not None else 0
-    m = k_cache.shape[1]
+    L, B, m, hkv, d = k_cache.shape
     pos = jnp.asarray(pos, jnp.int32)
     if _native_enabled(kernel):
-        B, _, hkv, d = k_cache.shape
         chunk = pk.dense_chunk_for(m)
         chunks = m // chunk
         kv_off, stride_kv = _shard_geometry(i, n, m, layout)
-        k_pool = k_cache.reshape(B * chunks, chunk, hkv, d)
-        v_pool = v_cache.reshape(B * chunks, chunk, hkv, v_cache.shape[-1])
+        k_pool = k_cache.reshape(L, B * chunks, chunk, hkv, d)
+        v_pool = v_cache.reshape(L, B * chunks, chunk, hkv, v_cache.shape[-1])
         bt = jnp.arange(B * chunks, dtype=jnp.int32).reshape(B, chunks)
 
         def run(_):
             return pk.paged_flash_decode(
-                q, k_pool, v_pool, bt, pos, kv_off,
+                q, k_pool, v_pool, bt, pos, kv_off, layer,
                 stride_kv=stride_kv, window=window, scale=scale,
             )
 
         o, lse = _maybe_pruned(run, q, pos, i, n, m, layout, window, prune)
     else:
         o, lse = _maybe_pruned_partial(
-            q, k_cache, v_cache, pos, i, n, m, layout, window, scale, prune
+            q, k_cache[layer], v_cache[layer], pos, i, n, m, layout, window, scale, prune
         )
     return _psum_combine(o, lse, axis_name, q.dtype)
 
@@ -276,23 +279,13 @@ def sharded_cache_decode(
 # --------------------------------------------------------------------------
 #
 # Quantized pools: when the pool dtype is int8 / fp8-e4m3 a fp32 scale table
-# [num_pages, page_size, Hkv] rides next to each pool, indexed by the SAME
+# [L, num_pages, page_size, Hkv] rides next to each pool, indexed by the SAME
 # (page, offset) the pool scatter/gather uses.  Scales are per token per
 # kv-head (amax over D only — see core/kv_quant.py), so every write path
 # (decode append, chunk prefill, speculative verify) quantizes its new
 # tokens independently and never re-quantizes resident positions.  The
 # update entries quantize when handed scale tables; the gather oracle
 # dequantizes; the native kernel dequantizes in VMEM after each page's DMA.
-
-
-def _pool_kv_dtype(pool) -> str:
-    """Storage mode of a pool array, inferred from its dtype."""
-    if pool.dtype == jnp.int8:
-        return "int8"
-    f8 = kv_quant.fp8_dtype()
-    if f8 is not None and pool.dtype == jnp.dtype(f8):
-        return "fp8"
-    return "fp"
 
 
 def _page_coords(pos, i, n: int, page_size: int, max_pages: int, layout: str):
@@ -305,26 +298,28 @@ def _page_coords(pos, i, n: int, page_size: int, max_pages: int, layout: str):
 
 
 def paged_cache_update(
-    k_pool: jnp.ndarray,  # [num_pages, page_size, Hkv, D] local page pool
+    k_pool: jnp.ndarray,  # [L, num_pages, page_size, Hkv, D] local page pools
     v_pool: jnp.ndarray,
     k_new: jnp.ndarray,  # [B, 1, Hkv, D] replicated across the axis
     v_new: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_pages] int32; -1 = unallocated
     pos,  # int32 scalar or [B] vector
+    layer,  # int32: the layer written
     axis_name: Optional[str],
     n: int,
     layout: str = "striped",
-    k_scale: Optional[jnp.ndarray] = None,  # [num_pages, page_size, Hkv] f32
+    k_scale: Optional[jnp.ndarray] = None,  # [L, num_pages, page_size, Hkv] f32
     v_scale: Optional[jnp.ndarray] = None,
 ):
-    """Scatter-by-block-table append: owner shard -> (page, offset).  Rows
-    past virtual capacity or pointing at unallocated pages are dropped (the
-    allocator only hands live slots a writable tail page).  With scale
-    tables the new token is quantized to the pool dtype and its per-(token,
-    head) scales scatter through the SAME coordinates; returns a 4-tuple
-    ``(k_pool, v_pool, k_scale, v_scale)`` in that case."""
+    """Scatter-by-block-table append: owner shard -> (page, offset) of layer
+    ``layer``, one scatter into the stacked pool.  Rows past virtual
+    capacity or pointing at unallocated pages are dropped (the allocator
+    only hands live slots a writable tail page).  With scale tables the new
+    token is quantized to the pool dtype and its per-(token, head) scales
+    scatter through the SAME coordinates; returns a 4-tuple ``(k_pool,
+    v_pool, k_scale, v_scale)`` in that case."""
     i = lax.axis_index(axis_name) if axis_name is not None else 0
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+    num_pages, page_size = k_pool.shape[1], k_pool.shape[2]
     max_pages = block_table.shape[1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (k_new.shape[0],))
     write, lp, off = _page_coords(pos, i, n, page_size, max_pages, layout)
@@ -334,46 +329,48 @@ def paged_cache_update(
     write = write & (phys >= 0)
     # out-of-range page index -> scatter drops the row entirely
     page_idx = jnp.where(write, phys, num_pages)
-    quantized = k_scale is not None
-    kv_dtype = _pool_kv_dtype(k_pool)
-    out = []
-    for pool, scales, new in ((k_pool, k_scale, k_new), (v_pool, v_scale, v_new)):
-        if quantized:
-            q, s = kv_quant.quantize(new[:, 0], kv_dtype)
-            out.append(pool.at[page_idx, off].set(q, mode="drop"))
-            out.append(scales.at[page_idx, off].set(s, mode="drop"))
-        else:
-            out.append(pool.at[page_idx, off].set(new[:, 0].astype(pool.dtype), mode="drop"))
-    if quantized:
-        return out[0], out[2], out[1], out[3]
-    return out[0], out[1]
+    return _store_pages(
+        k_pool, v_pool, k_scale, v_scale, (layer, page_idx, off), k_new[:, 0], v_new[:, 0]
+    )
 
 
-def paged_cache_gather(k_pool, v_pool, block_table, k_scale=None, v_scale=None):
-    """Materialize each row's dense local view from its pages: [B, m, Hkv, D]
-    with m = max_pages * page_size, in the SAME local-position order as the
-    dense cache slice (so the band math is shared verbatim).  Unallocated
-    pages clamp to page 0 — whatever is there is hidden behind the band.
-    Quantized pools (scale tables passed) gather scales through the same
-    index and dequantize to fp32 — the reference path for REPRO_KERNELS=ref
-    and non-Pallas platforms."""
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+def _store_pages(k_pool, v_pool, k_scale, v_scale, index, k_new, v_new):
+    """``kv_quant.store`` on the pools (and a quantized pool's scales):
+    returns ``(k_pool, v_pool)`` or ``(k_pool, v_pool, k_scale, v_scale)``."""
+    kv = {"k": k_pool, "v": v_pool}
+    if k_scale is not None:
+        kv.update(k_scale=k_scale, v_scale=v_scale)
+    kv = kv_quant.store(kv, index, k_new, v_new)
+    return tuple(kv[name] for name in ("k", "v", "k_scale", "v_scale") if name in kv)
+
+
+def paged_cache_gather(k_pool, v_pool, block_table, layer, k_scale=None, v_scale=None):
+    """Materialize each row's dense local view of layer ``layer`` from its
+    pages: [B, m, Hkv, D] with m = max_pages * page_size, in the SAME
+    local-position order as the dense cache slice (so the band math is
+    shared verbatim); one gather at ``[layer, pages]`` of the stacked pool.
+    Unallocated pages clamp to page 0 — whatever is there is hidden behind
+    the band.  Quantized pools (scale tables passed) gather scales through
+    the same index and dequantize to fp32 — the reference path for
+    REPRO_KERNELS=ref and non-Pallas platforms."""
+    num_pages = k_pool.shape[1]
     idx = jnp.clip(block_table, 0, num_pages - 1)  # [B, max_pages]
     out = []
     for pool, scales in ((k_pool, k_scale), (v_pool, v_scale)):
-        pages = pool[idx]  # [B, max_pages, page_size, Hkv, D]
+        pages = pool[layer, idx]  # [B, max_pages, page_size, Hkv, D]
         if scales is not None:
-            pages = kv_quant.dequantize(pages, scales[idx])
-        out.append(pages.reshape((idx.shape[0], -1) + pool.shape[2:]))
+            pages = kv_quant.dequantize(pages, scales[layer, idx])
+        out.append(pages.reshape((idx.shape[0], -1) + pool.shape[3:]))
     return out[0], out[1]
 
 
 def paged_cache_decode(
     q: jnp.ndarray,  # [B, 1, H, D] replicated over the axis
-    k_pool: jnp.ndarray,  # [num_pages, page_size, Hkv, D] local page pool
+    k_pool: jnp.ndarray,  # [L, num_pages, page_size, Hkv, D] local page pools
     v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_pages] int32
     pos,  # int32 scalar or [B] vector
+    layer,  # int32: the layer read
     axis_name: Optional[str],
     n: int,
     *,
@@ -382,18 +379,19 @@ def paged_cache_decode(
     scale: Optional[float] = None,
     prune: bool = True,
     kernel: str = "gather",  # gather | native (block table read in-kernel)
-    k_scale: Optional[jnp.ndarray] = None,  # [num_pages, page_size, Hkv] f32
+    k_scale: Optional[jnp.ndarray] = None,  # [L, num_pages, page_size, Hkv] f32
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Paged decode partial + psum combine.  ``kernel="gather"`` materializes
     each row's dense local view from its pages and runs the identical banded
     partial the dense path uses (the correctness oracle); ``"native"`` hands
-    the pool and the block table straight to the paged Pallas kernel — no
-    gathered intermediate, HBM traffic follows allocated depth.  Quantized
-    pools hand their scale tables along: the native kernel dequantizes in
-    VMEM after each page's DMA, the gather path dequantizes in the gather."""
+    the stacked pool, the layer and the block table straight to the paged
+    Pallas kernel — no gathered intermediate, HBM traffic follows allocated
+    depth.  Quantized pools hand their scale tables along: the native kernel
+    dequantizes in VMEM after each page's DMA, the gather path dequantizes
+    in the gather."""
     i = lax.axis_index(axis_name) if axis_name is not None else 0
-    page_size, max_pages = k_pool.shape[1], block_table.shape[1]
+    page_size, max_pages = k_pool.shape[2], block_table.shape[1]
     m = max_pages * page_size
     pos = jnp.asarray(pos, jnp.int32)
     if _native_enabled(kernel):
@@ -401,14 +399,16 @@ def paged_cache_decode(
 
         def run(_):
             return pk.paged_flash_decode(
-                q, k_pool, v_pool, block_table, pos, kv_off,
+                q, k_pool, v_pool, block_table, pos, kv_off, layer,
                 stride_kv=stride_kv, window=window, scale=scale,
                 k_scale=k_scale, v_scale=v_scale,
             )
 
         o, lse = _maybe_pruned(run, q, pos, i, n, m, layout, window, prune)
     else:
-        k_loc, v_loc = paged_cache_gather(k_pool, v_pool, block_table, k_scale, v_scale)
+        k_loc, v_loc = paged_cache_gather(
+            k_pool, v_pool, block_table, layer, k_scale, v_scale
+        )
         o, lse = _maybe_pruned_partial(
             q, k_loc, v_loc, pos, i, n, m, layout, window, scale, prune
         )
@@ -431,24 +431,26 @@ def paged_cache_decode(
 
 
 def sharded_cache_chunk_update(
-    k_cache: jnp.ndarray,  # [B, m, Hkv, D] local slice
+    k_cache: jnp.ndarray,  # [L, B, m, Hkv, D] local slices, stacked over layers
     v_cache: jnp.ndarray,
     k_new: jnp.ndarray,  # [B, C, Hkv, D] replicated across the axis
     v_new: jnp.ndarray,
     starts: jnp.ndarray,  # [B] int32: global position of each row's chunk base
     lens: jnp.ndarray,  # [B] int32: valid tokens per row (0 = inactive row)
     write_starts: jnp.ndarray,  # [B] int32: skip writes below this position
+    layer,  # int32: the layer written
     axis_name: Optional[str],
     n: int,
     layout: str = "striped",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter a C-token chunk per row into the local cache slice.  Positions
-    below ``write_starts`` (a shared prefix already resident) and at/after
-    ``starts + lens`` are dropped; distinct owned positions of one row map to
-    distinct local slots, so the scatter has no duplicate coordinates."""
+    """Scatter a C-token chunk per row into layer ``layer``'s local cache
+    slice.  Positions below ``write_starts`` (a shared prefix already
+    resident) and at/after ``starts + lens`` are dropped; distinct owned
+    positions of one row map to distinct local slots, so the scatter has no
+    duplicate coordinates."""
     i = lax.axis_index(axis_name) if axis_name is not None else 0
     B, C = k_new.shape[0], k_new.shape[1]
-    m = k_cache.shape[1]
+    m = k_cache.shape[2]
     starts = jnp.asarray(starts, jnp.int32)
     c = jnp.arange(C, dtype=jnp.int32)
     pos = starts[:, None] + c[None, :]  # [B, C]
@@ -465,7 +467,9 @@ def sharded_cache_chunk_update(
     b_idx = jnp.where(write, b, B)
     out = []
     for cache, new in ((k_cache, k_new), (v_cache, v_new)):
-        out.append(cache.at[b_idx, slot].set(new.astype(cache.dtype), mode="drop"))
+        out.append(
+            cache.at[layer, b_idx, slot].set(new.astype(cache.dtype), mode="drop")
+        )
     return out[0], out[1]
 
 
@@ -488,9 +492,10 @@ def _chunk_banded_partial(q, k_loc, v_loc, starts, kv_off, stride_kv, hi, scale)
 
 def sharded_cache_chunk_decode(
     q: jnp.ndarray,  # [B, C, H, D] chunk queries, replicated over the axis
-    k_cache: jnp.ndarray,  # [B, m, Hkv, D] local slice (chunk already written)
+    k_cache: jnp.ndarray,  # [L, B, m, Hkv, D] local slices (chunk already written)
     v_cache: jnp.ndarray,
     starts,  # int32 [B]: global position of each row's chunk base
+    layer,  # int32: the layer read
     axis_name: Optional[str],
     n: int,
     *,
@@ -504,7 +509,7 @@ def sharded_cache_chunk_decode(
     as single-token decode; the window-prune bound widens by C - 1 because the
     oldest row's window starts C - 1 earlier than the newest's."""
     i = lax.axis_index(axis_name) if axis_name is not None else 0
-    m = k_cache.shape[1]
+    m = k_cache.shape[2]
     C = q.shape[1]
     starts = jnp.asarray(starts, jnp.int32)
     kv_off, stride_kv = _shard_geometry(i, n, m, layout)
@@ -512,7 +517,7 @@ def sharded_cache_chunk_decode(
 
     def run(_):
         return _chunk_banded_partial(
-            q, k_cache, v_cache, starts, kv_off, stride_kv, hi, scale
+            q, k_cache[layer], v_cache[layer], starts, kv_off, stride_kv, hi, scale
         )
 
     win_eff = (window + C - 1) if window else None
@@ -521,7 +526,7 @@ def sharded_cache_chunk_decode(
 
 
 def paged_cache_chunk_update(
-    k_pool: jnp.ndarray,  # [num_pages, page_size, Hkv, D] local page pool
+    k_pool: jnp.ndarray,  # [L, num_pages, page_size, Hkv, D] local page pools
     v_pool: jnp.ndarray,
     k_new: jnp.ndarray,  # [B, C, Hkv, D] replicated across the axis
     v_new: jnp.ndarray,
@@ -529,22 +534,24 @@ def paged_cache_chunk_update(
     starts: jnp.ndarray,  # [B] int32
     lens: jnp.ndarray,  # [B] int32 (0 = inactive row)
     write_starts: jnp.ndarray,  # [B] int32: skip writes below this position
+    layer,  # int32: the layer written
     axis_name: Optional[str],
     n: int,
     layout: str = "striped",
-    k_scale: Optional[jnp.ndarray] = None,  # [num_pages, page_size, Hkv] f32
+    k_scale: Optional[jnp.ndarray] = None,  # [L, num_pages, page_size, Hkv] f32
     v_scale: Optional[jnp.ndarray] = None,
 ):
-    """Chunk append through the block table: the allocator pre-books every
-    prompt page at admission, so a chunk never lands on an unallocated page;
-    shared-prefix positions (below ``write_starts``) are skipped so CoW pages
-    are never touched mid-prefill.  With scale tables (quantized pool) each
-    chunk token quantizes independently — per-(token, head) scales mean
-    resident positions are never re-quantized — and continuous prefill +
-    speculative verify write quantized exactly like decode.  Returns a
-    4-tuple ``(k_pool, v_pool, k_scale, v_scale)`` in that case."""
+    """Chunk append through the block table into layer ``layer``: the
+    allocator pre-books every prompt page at admission, so a chunk never
+    lands on an unallocated page; shared-prefix positions (below
+    ``write_starts``) are skipped so CoW pages are never touched
+    mid-prefill.  With scale tables (quantized pool) each chunk token
+    quantizes independently — per-(token, head) scales mean resident
+    positions are never re-quantized — and continuous prefill + speculative
+    verify write quantized exactly like decode.  Returns a 4-tuple
+    ``(k_pool, v_pool, k_scale, v_scale)`` in that case."""
     i = lax.axis_index(axis_name) if axis_name is not None else 0
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+    num_pages, page_size = k_pool.shape[1], k_pool.shape[2]
     max_pages = block_table.shape[1]
     B, C = k_new.shape[0], k_new.shape[1]
     starts = jnp.asarray(starts, jnp.int32)
@@ -557,27 +564,16 @@ def paged_cache_chunk_update(
     phys = block_table[b, lp]
     write = write & (phys >= 0)
     page_idx = jnp.where(write, phys, num_pages)
-    quantized = k_scale is not None
-    kv_dtype = _pool_kv_dtype(k_pool)
-    out = []
-    for pool, scales, new in ((k_pool, k_scale, k_new), (v_pool, v_scale, v_new)):
-        if quantized:
-            q, s = kv_quant.quantize(new, kv_dtype)
-            out.append(pool.at[page_idx, off].set(q, mode="drop"))
-            out.append(scales.at[page_idx, off].set(s, mode="drop"))
-        else:
-            out.append(pool.at[page_idx, off].set(new.astype(pool.dtype), mode="drop"))
-    if quantized:
-        return out[0], out[2], out[1], out[3]
-    return out[0], out[1]
+    return _store_pages(k_pool, v_pool, k_scale, v_scale, (layer, page_idx, off), k_new, v_new)
 
 
 def paged_cache_chunk_decode(
     q: jnp.ndarray,  # [B, C, H, D] replicated over the axis
-    k_pool: jnp.ndarray,
+    k_pool: jnp.ndarray,  # [L, num_pages, page_size, Hkv, D] local page pools
     v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_pages] int32
     starts,  # int32 [B]
+    layer,  # int32: the layer read
     axis_name: Optional[str],
     n: int,
     *,
@@ -585,19 +581,22 @@ def paged_cache_chunk_decode(
     window: Optional[int] = None,
     scale: Optional[float] = None,
     prune: bool = True,
-    k_scale: Optional[jnp.ndarray] = None,  # [num_pages, page_size, Hkv] f32
+    k_scale: Optional[jnp.ndarray] = None,  # [L, num_pages, page_size, Hkv] f32
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Paged chunk attention: gather the row's pages into the dense local view
+    """Paged chunk attention: gather the row's pages of layer ``layer`` (one
+    gather at ``[layer, pages]``) into the dense local view
     and run the identical banded chunk partial (chunks are a prefill-side
     path — the paged decode kernel stays single-token).  Quantized pools
     dequantize in the gather."""
     i = lax.axis_index(axis_name) if axis_name is not None else 0
-    page_size, max_pages = k_pool.shape[1], block_table.shape[1]
+    page_size, max_pages = k_pool.shape[2], block_table.shape[1]
     m = max_pages * page_size
     C = q.shape[1]
     starts = jnp.asarray(starts, jnp.int32)
-    k_loc, v_loc = paged_cache_gather(k_pool, v_pool, block_table, k_scale, v_scale)
+    k_loc, v_loc = paged_cache_gather(
+        k_pool, v_pool, block_table, layer, k_scale, v_scale
+    )
     kv_off, stride_kv = _shard_geometry(i, n, m, layout)
     hi = (window - 1) if window else BAND_INF
 

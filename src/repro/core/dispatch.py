@@ -30,7 +30,6 @@ import os
 import tempfile
 from typing import Callable, Dict, List, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
@@ -55,7 +54,6 @@ from repro.core.simulator import HardwareModel
 from repro.core.tiling import best_square_a, stripe_permutation
 from repro.core.ulysses import ulysses_attention
 from repro.kernels import ops
-from repro.kernels.ref import BAND_INF
 
 __all__ = [
     "AttentionPlanConfig",
@@ -489,40 +487,35 @@ def _local_flash_apply(q, k, v, cfg: AttentionPlanConfig, seg=None):
 
 
 def _decode_step_local(
-    q, k_new, v_new, k_cache, v_cache, pos, cfg: AttentionPlanConfig,
+    q, k_new, v_new, k_cache, v_cache, pos, layer, cfg: AttentionPlanConfig,
     bt=None, ks=None, vs=None,
 ):
-    """One decode tick over the local cache slice (inside shard_map).  With
-    ``cfg.paged`` the caches are the physical page pool and ``bt`` is the
-    block table (owner shard -> (page, offset) instead of -> slot row);
-    ``ks``/``vs`` are the quantized pool's local scale tables — present, the
-    new token quantizes on write and the step returns them updated (a
-    5-tuple instead of 3)."""
+    """One decode tick over the local cache slices of layer ``layer``
+    (inside shard_map; caches stacked over layers).  With ``cfg.paged`` the
+    caches are the physical page pools and ``bt`` is the block table (owner
+    shard -> (page, offset) instead of -> slot row); ``ks``/``vs`` are the
+    quantized pool's local scale tables — present, the new token quantizes
+    on write and the step returns them updated (a 5-tuple instead of 3)."""
     if cfg.paged:
-        if ks is not None:
-            k_cache, v_cache, ks, vs = paged_cache_update(
-                k_cache, v_cache, k_new, v_new, bt, pos, cfg.axis_name, cfg.n,
-                layout=cfg.layout, k_scale=ks, v_scale=vs,
-            )
-        else:
-            k_cache, v_cache = paged_cache_update(
-                k_cache, v_cache, k_new, v_new, bt, pos, cfg.axis_name, cfg.n,
-                layout=cfg.layout,
-            )
+        upd = paged_cache_update(
+            k_cache, v_cache, k_new, v_new, bt, pos, layer, cfg.axis_name, cfg.n,
+            layout=cfg.layout, k_scale=ks, v_scale=vs,
+        )
+        k_cache, v_cache = upd[:2]
+        ks, vs = upd[2:] if ks is not None else (None, None)
         o = paged_cache_decode(
-            q, k_cache, v_cache, bt, pos, cfg.axis_name, cfg.n,
+            q, k_cache, v_cache, bt, pos, layer, cfg.axis_name, cfg.n,
             layout=cfg.layout, window=cfg.window, scale=cfg.scale,
             kernel=_resolve_decode_kernel(cfg.decode_kernel, paged=True),
             k_scale=ks, v_scale=vs,
         )
-        if ks is not None:
-            return o, k_cache, v_cache, ks, vs
-        return o, k_cache, v_cache
+        return (o, k_cache, v_cache) + ((ks, vs) if ks is not None else ())
     k_cache, v_cache = sharded_cache_update(
-        k_cache, v_cache, k_new, v_new, pos, cfg.axis_name, cfg.n, layout=cfg.layout
+        k_cache, v_cache, k_new, v_new, pos, layer, cfg.axis_name, cfg.n,
+        layout=cfg.layout,
     )
     o = sharded_cache_decode(
-        q, k_cache, v_cache, pos, cfg.axis_name, cfg.n,
+        q, k_cache, v_cache, pos, layer, cfg.axis_name, cfg.n,
         layout=cfg.layout, window=cfg.window, scale=cfg.scale,
         kernel=_resolve_decode_kernel(cfg.decode_kernel, paged=False),
     )
@@ -642,24 +635,28 @@ def decode_attention_step(
     q,  # [B, 1, H, D]
     k_new,  # [B, 1, Hkv, D]
     v_new,
-    k_cache,  # [B, cap(/n), Hkv, D]; sharded over the sequence axis — or,
-    v_cache,  # paged: the pool [num_pages, n*page_size, Hkv, D]
+    k_cache,  # [L, B, cap(/n), Hkv, D]; sharded over the sequence axis — or,
+    v_cache,  # paged: the pools [L, num_pages, n*page_size, Hkv, D]
     pos,  # int32 scalar, or [B] vector of per-slot positions
     ctx,
     *,
+    layer,  # int32: the layer of the stacked caches this step serves
     window: Optional[int] = None,
     layout: str = "striped",
     scale: Optional[float] = None,
     block_table=None,  # int32 [B, max_pages]: switches to the paged cache
     decode_kernel: Optional[str] = None,  # None -> ctx.decode_kernel
-    k_scale=None,  # [L?, num_pages, n*page_size, Hkv] f32: quantized pool
+    k_scale=None,  # [L, num_pages, n*page_size, Hkv] f32: quantized pool
     v_scale=None,
 ):
-    """One token of cache-based decode through the 'decode' backend.
+    """One token of cache-based decode through the 'decode' backend, for
+    layer ``layer`` of caches stacked over the model's layers: the append
+    scatters at ``[layer, ...]`` and the read indexes ``[layer, ...]``, so a
+    caller that carries the stack through its layer loop updates it in place.
 
-    Returns (o, new_k_cache, new_v_cache).  n == 1 runs the dense local
-    update + flash-decode; otherwise the sequence-sharded cache path.
-    Vector ``pos`` serves mixed-depth slots in one step (continuous batching).
+    Returns (o, new_k_cache, new_v_cache).  n == 1 runs the local update +
+    flash-decode; otherwise the sequence-sharded cache path.  Vector ``pos``
+    serves mixed-depth slots in one step (continuous batching).
 
     ``k_scale``/``v_scale`` (paged only) mark a QUANTIZED pool: pages hold
     int8/fp8 elements, the fp32 scale tables share the pool's sharding and
@@ -667,8 +664,8 @@ def decode_attention_step(
     native path), and the step returns ``(o, k_cache, v_cache, k_scale,
     v_scale)``.
 
-    ``block_table`` selects the PAGED cache: k/v are the physical page pool
-    (middle axis sharded over the sequence axis exactly like the dense cap
+    ``block_table`` selects the PAGED cache: k/v are the physical page pools
+    (position axis sharded over the sequence axis exactly like the dense cap
     axis) and each row's pages are resolved through the table.  The pool has
     no batch axis, so the paged step runs batch-REPLICATED over any data
     axes — every device applies the identical pool update (slots are few;
@@ -679,180 +676,91 @@ def decode_attention_step(
     """
     n = ctx.sp_size
     pos = jnp.asarray(pos, jnp.int32)
-    hi = (window - 1) if window else BAND_INF
+    layer = jnp.asarray(layer, jnp.int32)
     if decode_kernel is None:
         decode_kernel = getattr(ctx, "decode_kernel", "auto")
     if k_scale is not None and block_table is None:
         raise ValueError("k_scale/v_scale (quantized pool) require block_table")
-    if block_table is not None:
-        return _decode_attention_step_paged(
-            q, k_new, v_new, k_cache, v_cache, pos, block_table, ctx,
-            window=window, layout=layout, scale=scale, decode_kernel=decode_kernel,
-            k_scale=k_scale, v_scale=v_scale,
-        )
-    dense_kernel = _resolve_decode_kernel(decode_kernel, paged=False)
-    if n == 1:
-        if dense_kernel == "native":
-            # one shared update + paged decode call covers scalar AND
-            # vector pos (the kernel's grid is per-row, no vmap needed)
-            k_cache, v_cache = sharded_cache_update(
-                k_cache, v_cache, k_new, v_new, pos, None, 1, layout=layout
-            )
-            o = sharded_cache_decode(
-                q, k_cache, v_cache, pos, None, 1,
-                layout=layout, window=window, scale=scale, kernel="native",
-            )
-            return o.astype(q.dtype), k_cache, v_cache
-        if pos.ndim == 0:
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k_new.astype(k_cache.dtype), pos, axis=1
-            )
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v_new.astype(v_cache.dtype), pos, axis=1
-            )
-            band = jnp.stack([pos, jnp.int32(0), jnp.int32(0), jnp.int32(hi)])
-            o, _ = ops.block_attention(q, k_cache, v_cache, band, scale=scale)
-            return o.astype(q.dtype), k_cache, v_cache
-        # per-slot positions: row-wise scatter, then a row-wise band
-        cap = k_cache.shape[1]
-        write = pos < cap
-        slot = jnp.clip(pos, 0, cap - 1)
-        b = jnp.arange(k_cache.shape[0])
-        caches = []
-        for cache, new in ((k_cache, k_new), (v_cache, v_new)):
-            cur = cache[b, slot]
-            val = jnp.where(write[:, None, None], new[:, 0].astype(cache.dtype), cur)
-            caches.append(cache.at[b, slot].set(val))
-        k_cache, v_cache = caches
-
-        def one(qb, kb, vb, pb):
-            band = jnp.stack([pb, jnp.int32(0), jnp.int32(0), jnp.int32(hi)])
-            ob, _ = ops.block_attention(qb[None], kb[None], vb[None], band, scale=scale)
-            return ob[0]
-
-        o = jax.vmap(one)(q, k_cache, v_cache, pos)
-        return o.astype(q.dtype), k_cache, v_cache
-
-    cfg = AttentionPlanConfig(
-        backend="decode", axis_name=ctx.sp_axis, n=n,
-        window=window, layout=layout, scale=scale, decode_kernel=decode_kernel,
-    )
-    step = get_backend("decode").step
-
-    bs = ctx.eff_batch_spec(q.shape[0])
-    rep = P(bs, None, None, None)
-    cache_spec = P(bs, ctx.sp_axis, None, None)
-    pos_spec = P(bs) if pos.ndim else P()
-
-    f = shard_map(
-        lambda q, kn, vn, kc, vc, pos: step(q, kn, vn, kc, vc, pos, cfg),
-        mesh=ctx.shard_map_mesh(),
-        in_specs=(rep, rep, rep, cache_spec, cache_spec, pos_spec),
-        out_specs=(rep, cache_spec, cache_spec),
-        check_vma=False,
-    )
-    return f(q, k_new, v_new, k_cache, v_cache, pos)
-
-
-def _decode_attention_step_paged(
-    q, k_new, v_new, k_pool, v_pool, pos, block_table, ctx,
-    *, window, layout, scale, decode_kernel="auto", k_scale=None, v_scale=None,
-):
-    """Paged decode step: the pool's page axis is unsharded, its position
-    axis is sharded over the sequence axis; everything else is replicated
-    (see ``decode_attention_step``).  Quantized pools thread their scale
-    tables with the pool's sharding (the scale's position axis is the pool's
-    position axis) and get them back updated."""
-    n = ctx.sp_size
-    bt = jnp.asarray(block_table, jnp.int32)
-    kernel = _resolve_decode_kernel(decode_kernel, paged=True)
+    paged = block_table is not None
+    _resolve_decode_kernel(decode_kernel, paged=paged)  # a typo fails here, on every route
     quantized = k_scale is not None
-    if n == 1:
-        if quantized:
-            k_pool, v_pool, k_scale, v_scale = paged_cache_update(
-                k_pool, v_pool, k_new, v_new, bt, pos, None, 1, layout=layout,
-                k_scale=k_scale, v_scale=v_scale,
-            )
-        else:
-            k_pool, v_pool = paged_cache_update(
-                k_pool, v_pool, k_new, v_new, bt, pos, None, 1, layout=layout
-            )
-        o = paged_cache_decode(
-            q, k_pool, v_pool, bt, pos, None, 1,
-            layout=layout, window=window, scale=scale, kernel=kernel,
-            k_scale=k_scale, v_scale=v_scale,
-        )
-        if quantized:
-            return o, k_pool, v_pool, k_scale, v_scale
-        return o, k_pool, v_pool
-
+    bt = jnp.asarray(block_table, jnp.int32) if paged else None
     cfg = AttentionPlanConfig(
-        backend="decode", axis_name=ctx.sp_axis, n=n,
-        window=window, layout=layout, scale=scale, paged=True,
-        decode_kernel=kernel,
-        kv_dtype=("int8" if k_pool.dtype == jnp.int8 else "fp8") if quantized else "fp",
+        backend="decode", axis_name=ctx.sp_axis if n > 1 else None, n=max(n, 1),
+        window=window, layout=layout, scale=scale, paged=paged,
+        decode_kernel=decode_kernel, kv_dtype=kv_quant.kv_dtype_of(k_cache),
     )
+    if n <= 1:
+        return _decode_step_local(
+            q, k_new, v_new, k_cache, v_cache, pos, layer, cfg,
+            bt=bt, ks=k_scale, vs=v_scale,
+        )
     step = get_backend("decode").step
-    rep = P(None, None, None, None)
-    pool_spec = P(None, ctx.sp_axis, None, None)
     pos_spec = P(None) if pos.ndim else P()
-    if quantized:
-        scale_spec = P(None, ctx.sp_axis, None)
+    if not paged:
+        bs = ctx.eff_batch_spec(q.shape[0])
+        rep = P(bs, None, None, None)
+        cache_spec = P(None, bs, ctx.sp_axis, None, None)
         f = shard_map(
-            lambda q, kn, vn, kp, vp, pos, bt, ks, vs: step(
-                q, kn, vn, kp, vp, pos, cfg, bt=bt, ks=ks, vs=vs
-            ),
+            lambda q, kn, vn, kc, vc, pos, l: step(q, kn, vn, kc, vc, pos, l, cfg),
             mesh=ctx.shard_map_mesh(),
-            in_specs=(rep, rep, rep, pool_spec, pool_spec, pos_spec,
-                      P(None, None), scale_spec, scale_spec),
-            out_specs=(rep, pool_spec, pool_spec, scale_spec, scale_spec),
+            in_specs=(rep, rep, rep, cache_spec, cache_spec,
+                      P(bs) if pos.ndim else P(), P()),
+            out_specs=(rep, cache_spec, cache_spec),
             check_vma=False,
         )
-        return f(q, k_new, v_new, k_pool, v_pool, pos, bt, k_scale, v_scale)
+        return f(q, k_new, v_new, k_cache, v_cache, pos, layer)
+    # the pool's page axis is unsharded, its position axis is sharded over
+    # the sequence axis; everything else is replicated.  Quantized pools
+    # thread their scale tables with the pool's sharding (the scale's
+    # position axis is the pool's position axis) and get them back updated
+    rep = P(None, None, None, None)
+    pool_spec = P(None, None, ctx.sp_axis, None, None)
+    scale_spec = P(None, None, ctx.sp_axis, None)
+    scales = (k_scale, v_scale) if quantized else ()
     f = shard_map(
-        lambda q, kn, vn, kp, vp, pos, bt: step(q, kn, vn, kp, vp, pos, cfg, bt=bt),
+        lambda q, kn, vn, kp, vp, pos, l, bt, *sc: step(
+            q, kn, vn, kp, vp, pos, l, cfg, bt=bt, ks=sc[0] if sc else None,
+            vs=sc[1] if sc else None,
+        ),
         mesh=ctx.shard_map_mesh(),
-        in_specs=(rep, rep, rep, pool_spec, pool_spec, pos_spec, P(None, None)),
-        out_specs=(rep, pool_spec, pool_spec),
+        in_specs=(rep, rep, rep, pool_spec, pool_spec, pos_spec, P(), P(None, None))
+        + (scale_spec,) * len(scales),
+        out_specs=(rep, pool_spec, pool_spec) + (scale_spec,) * len(scales),
         check_vma=False,
     )
-    return f(q, k_new, v_new, k_pool, v_pool, pos, bt)
+    return f(q, k_new, v_new, k_cache, v_cache, pos, layer, bt, *scales)
 
 
 def _chunk_step_local(
-    q, k_new, v_new, k_cache, v_cache, starts, lens, wstarts,
+    q, k_new, v_new, k_cache, v_cache, starts, lens, wstarts, layer,
     cfg: AttentionPlanConfig, bt=None, ks=None, vs=None,
 ):
-    """One prefill chunk over the local cache slice (inside shard_map):
-    scatter the chunk's KV by absolute position, then prefix-causal chunk
-    attention over everything resident.  ``ks``/``vs`` carry a quantized
-    pool's scale tables (chunked prefill and speculative verify write
-    quantized exactly like decode); present, the step returns a 5-tuple."""
+    """One prefill chunk over layer ``layer``'s local cache slices (inside
+    shard_map; caches stacked over layers): scatter the chunk's KV by
+    absolute position, then prefix-causal chunk attention over everything
+    resident.  ``ks``/``vs`` carry a quantized pool's scale tables (chunked
+    prefill and speculative verify write quantized exactly like decode);
+    present, the step returns a 5-tuple."""
     if cfg.paged:
-        if ks is not None:
-            k_cache, v_cache, ks, vs = paged_cache_chunk_update(
-                k_cache, v_cache, k_new, v_new, bt, starts, lens, wstarts,
-                cfg.axis_name, cfg.n, layout=cfg.layout, k_scale=ks, v_scale=vs,
-            )
-        else:
-            k_cache, v_cache = paged_cache_chunk_update(
-                k_cache, v_cache, k_new, v_new, bt, starts, lens, wstarts,
-                cfg.axis_name, cfg.n, layout=cfg.layout,
-            )
+        upd = paged_cache_chunk_update(
+            k_cache, v_cache, k_new, v_new, bt, starts, lens, wstarts, layer,
+            cfg.axis_name, cfg.n, layout=cfg.layout, k_scale=ks, v_scale=vs,
+        )
+        k_cache, v_cache = upd[:2]
+        ks, vs = upd[2:] if ks is not None else (None, None)
         o = paged_cache_chunk_decode(
-            q, k_cache, v_cache, bt, starts, cfg.axis_name, cfg.n,
+            q, k_cache, v_cache, bt, starts, layer, cfg.axis_name, cfg.n,
             layout=cfg.layout, window=cfg.window, scale=cfg.scale,
             k_scale=ks, v_scale=vs,
         )
-        if ks is not None:
-            return o, k_cache, v_cache, ks, vs
-        return o, k_cache, v_cache
+        return (o, k_cache, v_cache) + ((ks, vs) if ks is not None else ())
     k_cache, v_cache = sharded_cache_chunk_update(
-        k_cache, v_cache, k_new, v_new, starts, lens, wstarts,
+        k_cache, v_cache, k_new, v_new, starts, lens, wstarts, layer,
         cfg.axis_name, cfg.n, layout=cfg.layout,
     )
     o = sharded_cache_chunk_decode(
-        q, k_cache, v_cache, starts, cfg.axis_name, cfg.n,
+        q, k_cache, v_cache, starts, layer, cfg.axis_name, cfg.n,
         layout=cfg.layout, window=cfg.window, scale=cfg.scale,
     )
     return o, k_cache, v_cache
@@ -862,13 +770,14 @@ def chunk_attention_step(
     q,  # [B, C, H, D] chunk queries (pad rows beyond lens compute garbage)
     k_new,  # [B, C, Hkv, D]
     v_new,
-    k_cache,  # [B, cap(/n), Hkv, D] — or, paged: the pool
+    k_cache,  # [L, B, cap(/n), Hkv, D] — or, paged: the pools
     v_cache,
     starts,  # int32 [B]: global position of each row's chunk base
     lens,  # int32 [B]: valid tokens per row (0 = inactive row, nothing written)
     write_starts,  # int32 [B]: skip KV writes below this position (shared prefix)
     ctx,
     *,
+    layer,  # int32: the layer of the stacked caches this chunk serves
     window: Optional[int] = None,
     layout: str = "striped",
     scale: Optional[float] = None,
@@ -876,10 +785,10 @@ def chunk_attention_step(
     k_scale=None,  # f32 scale tables: quantized pool (paged only)
     v_scale=None,
 ):
-    """One continuous-prefill chunk: C tokens of row b land at global
-    positions ``starts[b] .. starts[b]+lens[b]-1`` and attend prefix-causally
-    to every resident position (row i sees <= starts[b]+i, within the
-    window).  Returns (o, new_k_cache, new_v_cache) exactly like
+    """One continuous-prefill chunk for layer ``layer``: C tokens of row b
+    land at global positions ``starts[b] .. starts[b]+lens[b]-1`` and attend
+    prefix-causally to every resident position (row i sees <= starts[b]+i,
+    within the window).  Returns (o, new_k_cache, new_v_cache) exactly like
     ``decode_attention_step`` — it is the same banded partial + lse psum with
     a multi-row q, so chunked prefill reproduces one-shot prefill bit-for-bit
     on the reference backend.  Chunks always run the band/gather path; the
@@ -890,93 +799,54 @@ def chunk_attention_step(
     starts = jnp.asarray(starts, jnp.int32)
     lens = jnp.asarray(lens, jnp.int32)
     write_starts = jnp.asarray(write_starts, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
     if k_scale is not None and block_table is None:
         raise ValueError("k_scale/v_scale (quantized pool) require block_table")
-    if block_table is not None:
-        bt = jnp.asarray(block_table, jnp.int32)
-        quantized = k_scale is not None
-        if n == 1:
-            if quantized:
-                k_cache, v_cache, k_scale, v_scale = paged_cache_chunk_update(
-                    k_cache, v_cache, k_new, v_new, bt, starts, lens,
-                    write_starts, None, 1, layout=layout,
-                    k_scale=k_scale, v_scale=v_scale,
-                )
-            else:
-                k_cache, v_cache = paged_cache_chunk_update(
-                    k_cache, v_cache, k_new, v_new, bt, starts, lens,
-                    write_starts, None, 1, layout=layout,
-                )
-            o = paged_cache_chunk_decode(
-                q, k_cache, v_cache, bt, starts, None, 1,
-                layout=layout, window=window, scale=scale,
-                k_scale=k_scale, v_scale=v_scale,
-            )
-            if quantized:
-                return o, k_cache, v_cache, k_scale, v_scale
-            return o, k_cache, v_cache
-        cfg = AttentionPlanConfig(
-            backend="decode", axis_name=ctx.sp_axis, n=n,
-            window=window, layout=layout, scale=scale, paged=True,
-            kv_dtype=("int8" if k_cache.dtype == jnp.int8 else "fp8")
-            if quantized else "fp",
+    paged = block_table is not None
+    quantized = k_scale is not None
+    bt = jnp.asarray(block_table, jnp.int32) if paged else None
+    cfg = AttentionPlanConfig(
+        backend="decode", axis_name=ctx.sp_axis if n > 1 else None, n=max(n, 1),
+        window=window, layout=layout, scale=scale, paged=paged,
+        kv_dtype=kv_quant.kv_dtype_of(k_cache),
+    )
+    if n <= 1:
+        return _chunk_step_local(
+            q, k_new, v_new, k_cache, v_cache, starts, lens, write_starts, layer,
+            cfg, bt=bt, ks=k_scale, vs=v_scale,
         )
-        rep = P(None, None, None, None)
-        pool_spec = P(None, ctx.sp_axis, None, None)
-        if quantized:
-            scale_spec = P(None, ctx.sp_axis, None)
-            f = shard_map(
-                lambda q, kn, vn, kp, vp, st, ln, ws, bt, ks, vs: _chunk_step_local(
-                    q, kn, vn, kp, vp, st, ln, ws, cfg, bt=bt, ks=ks, vs=vs
-                ),
-                mesh=ctx.shard_map_mesh(),
-                in_specs=(rep, rep, rep, pool_spec, pool_spec,
-                          P(None), P(None), P(None), P(None, None),
-                          scale_spec, scale_spec),
-                out_specs=(rep, pool_spec, pool_spec, scale_spec, scale_spec),
-                check_vma=False,
-            )
-            return f(q, k_new, v_new, k_cache, v_cache, starts, lens,
-                     write_starts, bt, k_scale, v_scale)
+    if not paged:
+        bs = ctx.eff_batch_spec(q.shape[0])
+        rep = P(bs, None, None, None)
+        cache_spec = P(None, bs, ctx.sp_axis, None, None)
+        vec = P(bs)
         f = shard_map(
-            lambda q, kn, vn, kp, vp, st, ln, ws, bt: _chunk_step_local(
-                q, kn, vn, kp, vp, st, ln, ws, cfg, bt=bt
+            lambda q, kn, vn, kc, vc, st, ln, ws, l: _chunk_step_local(
+                q, kn, vn, kc, vc, st, ln, ws, l, cfg
             ),
             mesh=ctx.shard_map_mesh(),
-            in_specs=(rep, rep, rep, pool_spec, pool_spec,
-                      P(None), P(None), P(None), P(None, None)),
-            out_specs=(rep, pool_spec, pool_spec),
+            in_specs=(rep, rep, rep, cache_spec, cache_spec, vec, vec, vec, P()),
+            out_specs=(rep, cache_spec, cache_spec),
             check_vma=False,
         )
-        return f(q, k_new, v_new, k_cache, v_cache, starts, lens, write_starts, bt)
-    if n == 1:
-        k_cache, v_cache = sharded_cache_chunk_update(
-            k_cache, v_cache, k_new, v_new, starts, lens, write_starts,
-            None, 1, layout=layout,
-        )
-        o = sharded_cache_chunk_decode(
-            q, k_cache, v_cache, starts, None, 1,
-            layout=layout, window=window, scale=scale,
-        )
-        return o, k_cache, v_cache
-    cfg = AttentionPlanConfig(
-        backend="decode", axis_name=ctx.sp_axis, n=n,
-        window=window, layout=layout, scale=scale,
-    )
-    bs = ctx.eff_batch_spec(q.shape[0])
-    rep = P(bs, None, None, None)
-    cache_spec = P(bs, ctx.sp_axis, None, None)
-    vec = P(bs)
+        return f(q, k_new, v_new, k_cache, v_cache, starts, lens, write_starts, layer)
+    rep = P(None, None, None, None)
+    pool_spec = P(None, None, ctx.sp_axis, None, None)
+    scale_spec = P(None, None, ctx.sp_axis, None)
+    scales = (k_scale, v_scale) if quantized else ()
     f = shard_map(
-        lambda q, kn, vn, kc, vc, st, ln, ws: _chunk_step_local(
-            q, kn, vn, kc, vc, st, ln, ws, cfg
+        lambda q, kn, vn, kp, vp, st, ln, ws, l, bt, *sc: _chunk_step_local(
+            q, kn, vn, kp, vp, st, ln, ws, l, cfg, bt=bt,
+            ks=sc[0] if sc else None, vs=sc[1] if sc else None,
         ),
         mesh=ctx.shard_map_mesh(),
-        in_specs=(rep, rep, rep, cache_spec, cache_spec, vec, vec, vec),
-        out_specs=(rep, cache_spec, cache_spec),
+        in_specs=(rep, rep, rep, pool_spec, pool_spec, P(None), P(None), P(None),
+                  P(), P(None, None)) + (scale_spec,) * len(scales),
+        out_specs=(rep, pool_spec, pool_spec) + (scale_spec,) * len(scales),
         check_vma=False,
     )
-    return f(q, k_new, v_new, k_cache, v_cache, starts, lens, write_starts)
+    return f(q, k_new, v_new, k_cache, v_cache, starts, lens, write_starts, layer, bt,
+             *scales)
 
 
 def latent_wire_attention(

@@ -41,6 +41,8 @@ __all__ = [
     "storage_itemsize",
     "quantize",
     "dequantize",
+    "kv_dtype_of",
+    "store",
 ]
 
 KV_DTYPES = ("fp", "int8", "fp8")
@@ -113,3 +115,31 @@ def dequantize(q: jnp.ndarray, scale: Optional[jnp.ndarray]) -> jnp.ndarray:
     if scale is None:
         return q.astype(jnp.float32)
     return q.astype(jnp.float32) * scale[..., None].astype(jnp.float32)
+
+
+def kv_dtype_of(pool: jnp.ndarray) -> str:
+    """The ``kv_dtype`` a pool array stores, read from its element dtype."""
+    if pool.dtype == jnp.int8:
+        return "int8"
+    f8 = fp8_dtype()
+    if f8 is not None and pool.dtype == jnp.dtype(f8):
+        return "fp8"
+    return "fp"
+
+
+def store(kv: dict, index, k_new: jnp.ndarray, v_new: jnp.ndarray) -> dict:
+    """Write ``k_new``/``v_new`` at ``index`` of the attention cache ``kv``
+    (``{"k", "v"}``, plus ``"k_scale"``/``"v_scale"`` for a quantized pool,
+    which stores them quantized with their per-(token, kv-head) scales at
+    the same index).  Out-of-range indices are dropped.  Returns the updated
+    dict; under jit each write is one scatter into the array it is given."""
+    out = dict(kv)
+    for name, new in (("k", k_new), ("v", v_new)):
+        pool = kv[name]
+        if name + "_scale" in kv:
+            q, s = quantize(new, kv_dtype_of(pool))
+            out[name] = pool.at[index].set(q, mode="drop")
+            out[name + "_scale"] = kv[name + "_scale"].at[index].set(s, mode="drop")
+        else:
+            out[name] = pool.at[index].set(new.astype(pool.dtype), mode="drop")
+    return out

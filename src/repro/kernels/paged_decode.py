@@ -7,20 +7,25 @@ traffic scales with *virtual capacity*, not with how deep any request actually
 is.  This kernel reads the page pool **in place**:
 
   * the grid is **one step per slot**, ``(batch,)``.  The int32 block table,
-    the per-slot positions and ``kv_offset`` are **scalar-prefetched** into
-    SMEM; from them the step works out the slot's visible logical pages
-    ``[lp_lo, lp_hi]`` (depth, shard stride and sliding window) and runs a
-    ``lax.fori_loop`` over exactly those pages, nothing past them;
-  * the pool stays in HBM (``memory_space=pltpu.HBM``).  The loop walks the
-    visible pages in **blocks of P pages** (``P = max(1, 256 // page_size)``,
+    the per-slot positions, ``kv_offset`` and the layer index are
+    **scalar-prefetched** into SMEM; from them the step works out the slot's
+    visible logical pages ``[lp_lo, lp_hi]`` (depth, shard stride and
+    sliding window) and runs a ``lax.fori_loop`` over exactly those pages,
+    nothing past them;
+  * the pool is the model's whole stacked ``[L, num_pages, page_size, Hkv,
+    D]`` pool and stays in HBM (``memory_space=pltpu.HBM``); every page copy
+    reads ``pool.at[layer, page]``, so no layer's slice of the pool is ever
+    materialized around the call.  The loop walks the visible pages in
+    **blocks of P pages** (``P = max(1, 256 // page_size)``,
     about 256 tokens per block: 16 pages of the 16-token serving pool, 2 of
     the dense 128-token view).  Each page of a block is fetched with its own
     ``make_async_copy`` into a double-buffered VMEM block ``[2, P*page_size,
     Hkv, D]``; block j+1's copies are started before block j is computed,
     and the last step of a slot starts the next slot's first block, so the
     DMA engine is busy across slot boundaries too (grid axis ``arbitrary``).
-    Quantized pools fetch each page's ``[page_size, Hkv]`` scale tile through
-    the same page id into their own buffers and dequantize in VMEM;
+    Quantized pools fetch each page's ``[page_size, Hkv]`` scale tile of the
+    layer through the same page id into their own buffers and dequantize in
+    VMEM;
   * pages past the depth, pages the window hides and unallocated pages
     (block table ``-1``) are neither copied nor computed: a free slot costs
     one grid step and no DMA, and HBM bytes follow depth;
@@ -41,9 +46,10 @@ Geometry matches ``core/decode_attention.py`` verbatim: local slot ``j`` of a
 shard holds global position ``kv_offset + stride_kv * j`` (striped:
 ``(i, n)``; contiguous: ``(i*m, 1)``), and slot ``j`` lives at offset
 ``j % page_size`` of logical page ``j // page_size``.  A dense ``[B, m]``
-cache is the degenerate case: reshape to ``[B * (m/chunk), chunk]`` pages
-with the identity block table ``bt[b, c] = b * chunks + c`` — one implicit
-page run per row — and this same kernel serves the dense decode path too.
+cache is the degenerate case: reshape ``[L, B, m]`` to ``[L, B * (m/chunk),
+chunk]`` pages with the identity block table ``bt[b, c] = b * chunks + c`` —
+one implicit page run per row — and this same kernel serves the dense decode
+path too.
 
 TARGET: TPU v5e.  Off-TPU the kernel runs with ``interpret=True`` (CPU CI);
 ``REPRO_KERNELS=ref`` callers fall back to the gather path at the
@@ -108,12 +114,13 @@ def _decode_kernel(
     bt_ref,  # [B, max_pages] int32 block table; -1 = unallocated
     pos_ref,  # [B] int32 per-slot positions
     off_ref,  # [1] int32 kv_offset (may be traced from axis_index)
+    layer_ref,  # [1] int32 layer of the stacked pools this call reads
     # q block (VMEM) and the pools (HBM)
     q_ref,  # [1, H, D]
-    k_hbm,  # [num_pages, page_size, Hkv, D]
+    k_hbm,  # [L, num_pages, page_size, Hkv, D]
     v_hbm,
-    # quantized pools add the two [num_pages, page_size, Hkv] fp32 scale
-    # tables here; then outputs o [1,H,D] / lse [1,1,H]; then scratch: the
+    # quantized pools add the layer's two [num_pages, 1, lanes] fp32 scale
+    # rows here; then outputs o [1,H,D] / lse [1,1,H]; then scratch: the
     # K/V blocks [2, P*page_size, Hkv, D] (+ scale blocks), the DMA sems
     *rest,
     scale: float,
@@ -134,6 +141,7 @@ def _decode_kernel(
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
     b, nb = pl.program_id(0), pl.num_programs(0)
     kv_off = off_ref[0]
+    layer = layer_ref[0]
     P, T = block_pages, block_pages * page_size
     vis = functools.partial(
         _visible_pages, stride_kv=stride_kv, page_size=page_size,
@@ -151,8 +159,8 @@ def _decode_kernel(
         pid = jnp.maximum(pid, 0)
         rows = pl.ds(pl.multiple_of(i * page_size, page_size), page_size)
         cps = [
-            pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, rows], sems.at[0, buf]),
-            pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, rows], sems.at[1, buf]),
+            pltpu.make_async_copy(k_hbm.at[layer, pid], k_buf.at[buf, rows], sems.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[layer, pid], v_buf.at[buf, rows], sems.at[1, buf]),
         ]
         if quantized:
             cps += [
@@ -297,28 +305,31 @@ def _decode_kernel(
 
 def paged_flash_decode(
     q: jnp.ndarray,  # [B, 1, H, D] the new token's queries
-    k_pool: jnp.ndarray,  # [num_pages, page_size, Hkv, D] local page pool
+    k_pool: jnp.ndarray,  # [L, num_pages, page_size, Hkv, D] local page pools
     v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_pages] int32; -1 = unallocated
     pos,  # int32 scalar or [B]: attends to global positions <= pos
     kv_offset,  # int32 (may be traced): global position of local slot 0
+    layer,  # int32 (may be traced): the layer of the stacked pools to read
     *,
     stride_kv: int,
     window: Optional[int] = None,
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
-    k_scale: Optional[jnp.ndarray] = None,  # [num_pages, page_size, Hkv] f32
+    k_scale: Optional[jnp.ndarray] = None,  # [L, num_pages, page_size, Hkv] f32
     v_scale: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """This shard's decode partial straight off the page pool: returns
-    (o [B,1,H,D] in q.dtype, lse [B,H,1] fp32) — the same contract as the
-    gather path's banded partial, ready for the cross-shard psum combine.
+    """Layer ``layer``'s decode partial for this shard, straight off the
+    stacked page pool: returns (o [B,1,H,D] in q.dtype, lse [B,H,1] fp32) —
+    the same contract as the gather path's banded partial, ready for the
+    cross-shard psum combine.  The pools are read in place, so a caller that
+    carries them through its layer loop never copies a layer out.
 
     ``k_scale``/``v_scale`` mark a quantized pool (int8 / fp8 elements):
     each page's scale tile is fetched through the same page id and K/V are
     dequantized in VMEM right after the DMA."""
     B, _, H, D = q.shape
-    num_pages, page_size, hkv, _ = k_pool.shape
+    _, num_pages, page_size, hkv, _ = k_pool.shape
     max_pages = block_table.shape[1]
     if H % hkv:
         raise ValueError(f"H={H} not divisible by Hkv={hkv}")
@@ -328,6 +339,7 @@ def paged_flash_decode(
     hi = (window - 1) if window else BAND_INF
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     off = jnp.reshape(jnp.asarray(kv_offset, jnp.int32), (1,))
+    lyr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     bt = jnp.asarray(block_table, jnp.int32)
     P = pages_per_block(page_size)
     T = P * page_size
@@ -336,17 +348,19 @@ def paged_flash_decode(
     quantized = k_scale is not None
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)), hbm, hbm]
-    operands = [bt, pos, off, q[:, 0], k_pool, v_pool]
+    operands = [bt, pos, off, lyr, q[:, 0], k_pool, v_pool]
     scratch = [
         pltpu.VMEM((2, T, hkv, D), k_pool.dtype),
         pltpu.VMEM((2, T, hkv, v_pool.shape[-1]), v_pool.dtype),
     ]
     if quantized:
         # a DMA may only slice whole 128-lane rows, so each page's
-        # [page_size, Hkv] scale tile travels as one lane-dense row
+        # [page_size, Hkv] scale tile of the layer travels as one lane-dense
+        # row (a relayout of the layer's scales: 4 bytes per token and head)
         lanes = -(-page_size * hkv // 128) * 128
 
         def rows(s):
+            s = jax.lax.dynamic_index_in_dim(s, lyr[0], keepdims=False)
             s = s.astype(jnp.float32).reshape(num_pages, page_size * hkv)
             s = jnp.pad(s, ((0, 0), (0, lanes - page_size * hkv)))
             return s.reshape(num_pages, 1, lanes)
@@ -357,7 +371,7 @@ def paged_flash_decode(
     scratch.append(pltpu.SemaphoreType.DMA((2, 2)))  # [K|V, buffer]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=in_specs,
         out_specs=[

@@ -63,11 +63,12 @@ def decode_attention_step(
     q: jnp.ndarray,  # [B, 1, H, D]
     k_new: jnp.ndarray,  # [B, 1, Hkv, D]
     v_new: jnp.ndarray,
-    k_cache: jnp.ndarray,  # [B, cap(/n), Hkv, D] (paged: the page pool)
+    k_cache: jnp.ndarray,  # [L, B, cap(/n), Hkv, D] (paged: the page pools)
     v_cache: jnp.ndarray,
     pos,  # int32 scalar or [B] per-slot position vector
     ctx: ParallelCtx,
     *,
+    layer,  # int32: the layer of the stacked caches this step serves
     window: Optional[int] = None,
     layout: str = "striped",
     scale: Optional[float] = None,
@@ -76,13 +77,14 @@ def decode_attention_step(
     k_scale: Optional[jnp.ndarray] = None,  # f32 scale tables: quantized pool
     v_scale: Optional[jnp.ndarray] = None,
 ):
-    """Returns (o, new_k_cache, new_v_cache).  ``block_table`` is handed to
+    """Returns (o, new_k_cache, new_v_cache), the caches updated in layer
+    ``layer`` only.  ``block_table`` is handed to
     the decode backend verbatim; with the native kernel variant it is read
     in-kernel (scalar-prefetched), never gathered into a dense view.  With
     ``k_scale``/``v_scale`` (quantized paged pool) the return extends to
     ``(o, k_cache, v_cache, k_scale, v_scale)``."""
     return dispatch.decode_attention_step(
-        q, k_new, v_new, k_cache, v_cache, pos, ctx,
+        q, k_new, v_new, k_cache, v_cache, pos, ctx, layer=layer,
         window=window, layout=layout, scale=scale, block_table=block_table,
         decode_kernel=decode_kernel, k_scale=k_scale, v_scale=v_scale,
     )
@@ -92,13 +94,14 @@ def chunk_attention_step(
     q: jnp.ndarray,  # [B, C, H, D] chunk queries
     k_new: jnp.ndarray,  # [B, C, Hkv, D]
     v_new: jnp.ndarray,
-    k_cache: jnp.ndarray,  # [B, cap(/n), Hkv, D] (paged: the page pool)
+    k_cache: jnp.ndarray,  # [L, B, cap(/n), Hkv, D] (paged: the page pools)
     v_cache: jnp.ndarray,
     starts,  # int32 [B]: global position of each row's chunk base
     lens,  # int32 [B]: valid tokens per row (0 = inactive row)
     write_starts,  # int32 [B]: skip KV writes below this (shared prefix)
     ctx: ParallelCtx,
     *,
+    layer,  # int32: the layer of the stacked caches this chunk serves
     window: Optional[int] = None,
     layout: str = "striped",
     scale: Optional[float] = None,
@@ -111,7 +114,7 @@ def chunk_attention_step(
     updated scale tables when a quantized pool passes them)."""
     return dispatch.chunk_attention_step(
         q, k_new, v_new, k_cache, v_cache, starts, lens, write_starts, ctx,
-        window=window, layout=layout, scale=scale, block_table=block_table,
+        layer=layer, window=window, layout=layout, scale=scale, block_table=block_table,
         k_scale=k_scale, v_scale=v_scale,
     )
 

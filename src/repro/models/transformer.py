@@ -172,6 +172,46 @@ def _scan_layers(x, layers, body, ctx: ParallelCtx):
     return x, aux
 
 
+# the attention cache leaves: carried WHOLE through the layer loop, never
+# scanned as per-layer slices, so each layer's write is one scatter into the
+# stack and each read indexes it in place (a scanned slice would be copied
+# out of the stack and written back into a new one, every layer, every step)
+_KV_KEYS = ("k", "v", "k_scale", "v_scale")
+# cache entries every layer shares: per-slot positions, the block table
+_SHARED_KEYS = ("pos", "bt")
+
+
+def _split_cache(cache):
+    """(kv, per_layer): the attention K/V (and scale) stacks, carried whole,
+    and the per-layer state (SSM, cross-attention K/V) scanned slice by
+    slice."""
+    kv = {k: cache[k] for k in _KV_KEYS if k in cache}
+    per_layer = {k: v for k, v in cache.items() if k not in _KV_KEYS + _SHARED_KEYS}
+    return kv, per_layer
+
+
+def _cache_layer_loop(body, x, cache, layers, ctx: ParallelCtx, remat: bool = False):
+    """Run ``body(x, kv, lp, layer, cl) -> (x, kv, cl)`` over the stacked
+    layers: the attention stacks ``kv`` ride in the carry whole with the
+    layer index scanned beside the params, the per-layer state ``cl`` is
+    scanned.  Returns ``(x, cache)`` with every cache leaf but ``pos``/``bt``
+    replaced."""
+    kv, per_layer = _split_cache(cache)
+    num_layers = jax.tree.leaves(layers)[0].shape[0]
+
+    def f(carry, inp):
+        x, kv = carry
+        lp, layer, cl = inp
+        x, kv, cl = body(x, kv, lp, layer, cl)
+        return (x, kv), cl
+
+    if remat:
+        f = jax.checkpoint(f, prevent_cse=False)
+    xs = (layers, jnp.arange(num_layers, dtype=jnp.int32), per_layer)
+    (x, kv), per_layer = _stack_scan(f, (x, kv), xs, ctx)
+    return x, {**cache, **kv, **per_layer}
+
+
 # --------------------------------------------------------------------------
 # forward / loss
 # --------------------------------------------------------------------------
@@ -367,13 +407,22 @@ def _decode_attn_out(o, h_in, lp, cfg: ModelConfig):
     return h_in + o.reshape(B, S, -1) @ lp["wo"]
 
 
-def _decode_block(x, lp, cache_l, cfg: ModelConfig, ctx: ParallelCtx, pos, bt=None):
-    """One layer's decode. cache_l: dict of this layer's cache slices; ``bt``
-    is the (layer-shared) block table when the K/V cache is paged."""
+def _attn_update(out, kv):
+    """The ``(o, k, v[, k_scale, v_scale])`` of an attention cache step ->
+    ``(o, kv)`` with the updated stacks."""
+    return out[0], {**kv, **dict(zip(_KV_KEYS, out[1:]))}
+
+
+def _decode_block(x, lp, kv, layer, cache_l, cfg: ModelConfig, ctx: ParallelCtx, pos,
+                  bt=None):
+    """One layer's decode.  ``kv``: the attention cache stacks over every
+    layer (written at ``layer`` only); ``cache_l``: this layer's slices of
+    the per-layer state; ``bt`` is the (layer-shared) block table when the
+    K/V cache is paged.  Returns (y, kv, new cache_l)."""
     new_cache = dict(cache_l)
     if cfg.family == "ssm":
         y, new_cache["ssm"] = ssm_mod.ssm_decode_step(x, lp["ssm"], cache_l["ssm"], cfg)
-        return y, new_cache
+        return y, kv, new_cache
 
     h = rms_norm(x, lp["attn"]["ln"]) if cfg.norm == "rmsnorm" else layer_norm(
         x, lp["attn"]["ln"], lp["attn"]["ln_b"]
@@ -381,20 +430,11 @@ def _decode_block(x, lp, cache_l, cfg: ModelConfig, ctx: ParallelCtx, pos, bt=No
     q, k_new, v_new, scale = _decode_qkv(h, lp["attn"], cfg, pos)
     # the decode cache is ALWAYS striped (even for contiguous-train archs):
     # prefill restripes K/V once; appends then stay load-balanced forever
-    ks, vs = cache_l.get("k_scale"), cache_l.get("v_scale")
-    if ks is not None:
-        o, ck, cv, ks, vs = attn.decode_attention_step(
-            q, k_new, v_new, cache_l["k"], cache_l["v"], pos, ctx,
-            window=cfg.window, layout="striped", scale=scale, block_table=bt,
-            k_scale=ks, v_scale=vs,
-        )
-        new_cache["k_scale"], new_cache["v_scale"] = ks, vs
-    else:
-        o, ck, cv = attn.decode_attention_step(
-            q, k_new, v_new, cache_l["k"], cache_l["v"], pos, ctx,
-            window=cfg.window, layout="striped", scale=scale, block_table=bt,
-        )
-    new_cache["k"], new_cache["v"] = ck, cv
+    o, kv = _attn_update(attn.decode_attention_step(
+        q, k_new, v_new, kv["k"], kv["v"], pos, ctx, layer=layer,
+        window=cfg.window, layout="striped", scale=scale, block_table=bt,
+        k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
+    ), kv)
     y = _decode_attn_out(o, x, lp["attn"], cfg)
 
     if cfg.hybrid:
@@ -417,7 +457,7 @@ def _decode_block(x, lp, cache_l, cfg: ModelConfig, ctx: ParallelCtx, pos, bt=No
         y, _ = moe_mod.moe_block(y, lp["moe"], cfg, ctx)
     elif cfg.d_ff > 0:
         y = mlp_block(y, lp["mlp"], cfg, ctx)
-    return y, new_cache
+    return y, kv, new_cache
 
 
 def decode_step(params, cache, tokens, cfg: ModelConfig, ctx: ParallelCtx):
@@ -432,28 +472,23 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, ctx: ParallelCtx):
     A paged cache's block table ``cache["bt"]`` is threaded to the decode
     backend VERBATIM (layer-shared device operand): ``ctx.decode_kernel``
     picks whether it drives a page gather or is scalar-prefetched into the
-    native paged kernel (kernels/paged_decode.py)."""
+    native paged kernel (kernels/paged_decode.py).  The K/V stacks ride the
+    layer loop whole (``_cache_layer_loop``): under a jit that donates the
+    cache, the step updates them in place."""
     pos = cache["pos"]
     bt = cache.get("bt")  # paged K/V: block table, shared by every layer
     x = jnp.take(params["embed"], tokens, axis=0)
     x = ctx.constrain(x, None, None)
 
-    layer_cache = {k: v for k, v in cache.items() if k not in ("pos", "bt")}
+    def body(x, kv, lp, layer, cl):
+        return _decode_block(x, lp, kv, layer, cl, cfg, ctx, pos, bt=bt)
 
-    def body(x, inp):
-        lp, cl = inp
-        x, new_cl = _decode_block(x, lp, cl, cfg, ctx, pos, bt=bt)
-        return x, new_cl
-
-    x, new_layer_cache = _stack_scan(body, x, (params["layers"], layer_cache), ctx)
+    x, new_cache = _cache_layer_loop(body, x, cache, params["layers"], ctx)
     x = _final_norm(x, params, cfg)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head.astype(x.dtype)
     nxt = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
-    new_cache = dict(new_layer_cache)
     new_cache["pos"] = pos + 1
-    if bt is not None:
-        new_cache["bt"] = bt
     return nxt, new_cache, logits
 
 
@@ -490,7 +525,7 @@ def prefill_chunk(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cache
     lens = jnp.asarray(batch["lens"], jnp.int32)
     pos_set = jnp.asarray(batch["pos_set"], jnp.int32)
     C = tokens.shape[1]
-    x, new_layer_cache, bt = _chunk_forward(
+    x, new_cache = _chunk_forward(
         params, cfg, ctx, tokens, batch["starts"], lens, batch["write_starts"],
         cache,
     )
@@ -498,11 +533,7 @@ def prefill_chunk(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cache
     last = jnp.clip(lens - 1, 0, C - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B, 1, D]
     logits = x_last[:, 0] @ head.astype(x.dtype)  # [B, V]
-    new_cache = dict(cache)
-    new_cache.update(new_layer_cache)
     new_cache["pos"] = jnp.where(pos_set >= 0, pos_set, cache["pos"])
-    if bt is not None:
-        new_cache["bt"] = bt
     return logits, new_cache
 
 
@@ -511,7 +542,7 @@ def _chunk_forward(params, cfg: ModelConfig, ctx: ParallelCtx, tokens, starts,
     """Shared core of ``prefill_chunk`` and ``verify_step``: append a
     [B, C] chunk batch into the live cache through the banded multi-row
     attention path and return the final-norm hidden states for EVERY chunk
-    position.  Returns ``(x [B, C, D], new_layer_cache, bt)``."""
+    position.  Returns ``(x [B, C, D], new cache)``."""
     if cfg.ssm is not None or cfg.encoder_layers or cfg.frontend is not None:
         raise ValueError("chunked prefill serves attention-only decoder archs")
     starts = jnp.asarray(starts, jnp.int32)
@@ -522,42 +553,28 @@ def _chunk_forward(params, cfg: ModelConfig, ctx: ParallelCtx, tokens, starts,
     bt = cache.get("bt")  # paged K/V: block table, shared by every layer
     x = jnp.take(params["embed"], tokens, axis=0)
     x = ctx.constrain(x, None, None)
-    layer_cache = {k: v for k, v in cache.items() if k not in ("pos", "bt")}
 
-    def body(x, inp):
-        lp, cl = inp
-        new_cl = dict(cl)
+    def body(x, kv, lp, layer, cl):
         h = rms_norm(x, lp["attn"]["ln"]) if cfg.norm == "rmsnorm" else layer_norm(
             x, lp["attn"]["ln"], lp["attn"]["ln_b"]
         )
         q, k_new, v_new, scale = _decode_qkv(h, lp["attn"], cfg, positions)
         # the decode cache is ALWAYS striped; chunk rows scatter straight to
         # their owner shards exactly like single-token appends
-        ks, vs = cl.get("k_scale"), cl.get("v_scale")
-        if ks is not None:
-            o, ck, cv, ks, vs = attn.chunk_attention_step(
-                q, k_new, v_new, cl["k"], cl["v"], starts, lens, write_starts,
-                ctx, window=cfg.window, layout="striped", scale=scale,
-                block_table=bt, k_scale=ks, v_scale=vs,
-            )
-            new_cl["k_scale"], new_cl["v_scale"] = ks, vs
-        else:
-            o, ck, cv = attn.chunk_attention_step(
-                q, k_new, v_new, cl["k"], cl["v"], starts, lens, write_starts,
-                ctx, window=cfg.window, layout="striped", scale=scale,
-                block_table=bt,
-            )
-        new_cl["k"], new_cl["v"] = ck, cv
+        o, kv = _attn_update(attn.chunk_attention_step(
+            q, k_new, v_new, kv["k"], kv["v"], starts, lens, write_starts,
+            ctx, layer=layer, window=cfg.window, layout="striped", scale=scale,
+            block_table=bt, k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
+        ), kv)
         y = _decode_attn_out(o, x, lp["attn"], cfg)
         if cfg.moe is not None:
             y, _ = moe_mod.moe_block(y, lp["moe"], cfg, ctx)
         elif cfg.d_ff > 0:
             y = mlp_block(y, lp["mlp"], cfg, ctx)
-        return y, new_cl
+        return y, kv, cl
 
-    x, new_layer_cache = _stack_scan(body, x, (params["layers"], layer_cache), ctx)
-    x = _final_norm(x, params, cfg)
-    return x, new_layer_cache, bt
+    x, new_cache = _cache_layer_loop(body, x, cache, params["layers"], ctx)
+    return _final_norm(x, params, cfg), new_cache
 
 
 def verify_step(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cache,
@@ -598,7 +615,7 @@ def verify_step(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cache,
     starts = jnp.asarray(batch["starts"], jnp.int32)
     lens = jnp.asarray(batch["lens"], jnp.int32)
     B, K = tokens.shape
-    x, new_layer_cache, bt = _chunk_forward(
+    x, new_cache = _chunk_forward(
         params, cfg, ctx, tokens, starts, lens, batch["write_starts"], cache
     )
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -612,11 +629,7 @@ def verify_step(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cache,
     else:
         accepted = jnp.zeros((B,), jnp.int32)
     commit = jnp.where(lens > 0, jnp.minimum(accepted + 1, lens), 0)
-    new_cache = dict(cache)
-    new_cache.update(new_layer_cache)
     new_cache["pos"] = jnp.where(lens > 0, starts + commit, cache["pos"])
-    if bt is not None:
-        new_cache["bt"] = bt
     if return_logits:
         return y, commit, new_cache, logits
     return y, commit, new_cache
@@ -744,50 +757,22 @@ def prefill(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cache):
     else:
         cap = cache["k"].shape[2] if has_attn else None
         g_idx = _cache_scatter_indices(cfg, S, cap, ctx.sp_size) if has_attn else None
-    keys = [
-        k for k in ("k", "v", "k_scale", "v_scale", "ssm", "cross_k", "cross_v")
-        if k in cache
-    ]
-    layer_cache = {k: cache[k] for k in keys}
-    # quantized pool: prefill quantizes at write time, exactly like appends
-    kv_dtype = (
-        ("int8" if cache["k"].dtype == jnp.int8 else "fp8")
-        if "k_scale" in cache else "fp"
-    )
 
-    def _kv_for_cache(h, lp):
-        return _project_kv_for_cache(h, lp, cfg, positions)
-
-    def body(x, inp):
-        lp, cl = inp
+    def body(x, kv, lp, layer, cl):
         new_cl = dict(cl)
         aux = jnp.float32(0.0)
         if has_attn:
             h = rms_norm(x, lp["attn"]["ln"]) if cfg.norm == "rmsnorm" else layer_norm(
                 x, lp["attn"]["ln"], lp["attn"]["ln_b"]
             )
-            kk, vv = _kv_for_cache(h, lp["attn"])
-            if paged and kv_dtype != "fp":
-                qk, sk = kv_quant.quantize(kk[0], kv_dtype)
-                qv, sv = kv_quant.quantize(vv[0], kv_dtype)
-                new_cl["k"] = cl["k"].at[page_idx, col_idx].set(qk, mode="drop")
-                new_cl["v"] = cl["v"].at[page_idx, col_idx].set(qv, mode="drop")
-                new_cl["k_scale"] = cl["k_scale"].at[page_idx, col_idx].set(
-                    sk, mode="drop"
-                )
-                new_cl["v_scale"] = cl["v_scale"].at[page_idx, col_idx].set(
-                    sv, mode="drop"
-                )
-            elif paged:
-                new_cl["k"] = cl["k"].at[page_idx, col_idx].set(
-                    kk[0].astype(cl["k"].dtype), mode="drop"
-                )
-                new_cl["v"] = cl["v"].at[page_idx, col_idx].set(
-                    vv[0].astype(cl["v"].dtype), mode="drop"
-                )
+            kk, vv = _project_kv_for_cache(h, lp["attn"], cfg, positions)
+            # one scatter per array into the stacks; a quantized pool
+            # quantizes at write time, exactly like appends
+            if paged:
+                kv = kv_quant.store(kv, (layer, page_idx, col_idx), kk[0], vv[0])
             else:
-                new_cl["k"] = cl["k"].at[:, g_idx].set(kk.astype(cl["k"].dtype))
-                new_cl["v"] = cl["v"].at[:, g_idx].set(vv.astype(cl["v"].dtype))
+                rows = jnp.arange(kk.shape[0])[:, None]
+                kv = kv_quant.store(kv, (layer, rows, g_idx[None, :]), kk, vv)
         if cfg.encoder_layers:
             B = x.shape[0]
             new_cl["cross_k"] = (enc @ lp["xattn"]["wk"]).reshape(
@@ -815,11 +800,9 @@ def prefill(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cache):
                 x = mlp_block(x, lp["mlp"], cfg, ctx)
         else:
             x, aux = _decoder_block(x, lp, cfg, ctx, positions, enc=enc)
-        return x, new_cl
+        return x, kv, new_cl
 
-    if ctx.remat:
-        body = jax.checkpoint(body, prevent_cse=False)
-    x, new_layer_cache = _stack_scan(body, x, (params["layers"], layer_cache), ctx)
+    x, new_cache = _cache_layer_loop(body, x, cache, params["layers"], ctx, remat=ctx.remat)
     x = _final_norm(x, params, cfg)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     B = tokens.shape[0]
@@ -836,8 +819,6 @@ def prefill(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cache):
         x_last = jnp.take(x, last_idx[None], axis=1)
         new_pos = jnp.full((B,), S, jnp.int32)
     logits = x_last @ head.astype(x.dtype)
-    new_cache = dict(cache)
-    new_cache.update(new_layer_cache)
     if paged:
         # the pool cache's pos covers every slot; only this one was prefilled
         new_cache["pos"] = cache["pos"].at[slot].set(length_s)
@@ -880,11 +861,6 @@ def prefill_packed(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cach
     k_docs = slots.shape[0]
     n = ctx.sp_size
     paged = "bt" in cache
-    # quantized pool: packed prefill quantizes at write time like appends
-    kv_dtype = (
-        ("int8" if cache["k"].dtype == jnp.int8 else "fp8")
-        if "k_scale" in cache else "fp"
-    )
 
     x = jnp.take(params["embed"], tokens, axis=0)
     x = ctx.constrain(x, "seq", None)
@@ -916,41 +892,18 @@ def prefill_packed(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cach
         else:
             g_idx = positions
 
-    def body(x, inp):
-        lp, cl = inp
-        new_cl = dict(cl)
+    def body(x, kv, lp, layer, cl):
         h = rms_norm(x, lp["attn"]["ln"]) if cfg.norm == "rmsnorm" else layer_norm(
             x, lp["attn"]["ln"], lp["attn"]["ln_b"]
         )
         kk, vv = _project_kv_for_cache(h, lp["attn"], cfg, positions)
-        if kv_dtype != "fp":
-            qk, sk = kv_quant.quantize(kk[0], kv_dtype)
-            qv, sv = kv_quant.quantize(vv[0], kv_dtype)
-            new_cl["k"] = cl["k"].at[row_idx, g_idx].set(qk, mode="drop")
-            new_cl["v"] = cl["v"].at[row_idx, g_idx].set(qv, mode="drop")
-            new_cl["k_scale"] = cl["k_scale"].at[row_idx, g_idx].set(
-                sk, mode="drop"
-            )
-            new_cl["v_scale"] = cl["v_scale"].at[row_idx, g_idx].set(
-                sv, mode="drop"
-            )
-        else:
-            new_cl["k"] = cl["k"].at[row_idx, g_idx].set(
-                kk[0].astype(cl["k"].dtype), mode="drop"
-            )
-            new_cl["v"] = cl["v"].at[row_idx, g_idx].set(
-                vv[0].astype(cl["v"].dtype), mode="drop"
-            )
+        # one scatter per array into the stacks (a quantized pool quantizes
+        # at write time, like appends); pads are dropped
+        kv = kv_quant.store(kv, (layer, row_idx, g_idx), kk[0], vv[0])
         x, _ = _decoder_block(x, lp, cfg, ctx, positions, segments=segments)
-        return x, new_cl
+        return x, kv, cl
 
-    if ctx.remat:
-        body = jax.checkpoint(body, prevent_cse=False)
-    layer_cache = {
-        key: cache[key]
-        for key in ("k", "v", "k_scale", "v_scale") if key in cache
-    }
-    x, new_layer_cache = _stack_scan(body, x, (params["layers"], layer_cache), ctx)
+    x, new_cache = _cache_layer_loop(body, x, cache, params["layers"], ctx, remat=ctx.remat)
     x = _final_norm(x, params, cfg)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     # document d's last real token sits where segments == d AND positions ==
@@ -961,7 +914,5 @@ def prefill_packed(params, cfg: ModelConfig, ctx: ParallelCtx, batch: Dict, cach
     last_idx = jnp.argmax(match, axis=1)  # [k]
     x_last = x[0, last_idx]  # [k, D]
     logits = x_last @ head.astype(x.dtype)
-    new_cache = dict(cache)
-    new_cache.update(new_layer_cache)
     new_cache["pos"] = cache["pos"].at[slots].set(doc_lens)
     return logits, new_cache

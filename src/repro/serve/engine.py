@@ -75,6 +75,17 @@ def select_victim(slots, allocator, protect=()):
         return None
     return min(cands)[3]
 
+
+def _set_kv(cache, idx, value, keys):
+    """``cache[key][:, *idx] = value`` for each of ``keys`` (K/V and scale
+    stacks, indexed past their leading layer axis); out-of-range indices are
+    dropped.  Jitted with the cache donated, so the write is in place."""
+    out = dict(cache)
+    for key in keys:
+        out[key] = cache[key].at[(slice(None),) + tuple(idx)].set(value, mode="drop")
+    return out
+
+
 # engine totals each engine.tick span reports as its deltas (ServeEngine._counters)
 _TICK_COUNTERS = ("prefill_launches", "pages_allocated", "cow_copies", "prefix_hit_pages",
                   "bt_uploads", "preemptions", "retraced")
@@ -294,10 +305,16 @@ class ServeEngine:
         self.rejected_requests = 0
         self.health_sweeps = 0
         self.chaos_dropped_grants = 0
-        self._decode = jax.jit(self._decode_traced)
-        self._copy_pages = jax.jit(self._copy_pages_traced)
-        self._chunk_step = jax.jit(self._chunk_traced)
-        self._verify = jax.jit(self._verify_traced)
+        # every jitted step that returns the cache DONATES the one it is
+        # given: the pool is updated in place (the model carries its K/V
+        # stacks whole through the layer loop), so a tick neither copies
+        # the pool nor holds two of them.  The engine always replaces
+        # self._cache with the returned cache; nothing keeps the old one.
+        self._decode = jax.jit(self._decode_traced, donate_argnums=1)
+        self._copy_pages = jax.jit(self._copy_pages_traced, donate_argnums=0)
+        self._chunk_step = jax.jit(self._chunk_traced, donate_argnums=1)
+        self._verify = jax.jit(self._verify_traced, donate_argnums=1)
+        self._set_kv = jax.jit(_set_kv, static_argnames="keys", donate_argnums=0)
 
     # -- jitted paths -------------------------------------------------------
 
@@ -473,7 +490,7 @@ class ServeEngine:
                 return merged, first, logits[0, 0]
             return merged, first
 
-        jitted = jax.jit(fn)
+        jitted = jax.jit(fn, donate_argnums=1)
         self._prefill_fns[bucket] = jitted
         return jitted
 
@@ -540,7 +557,7 @@ class ServeEngine:
                 return cache, first, logits
             return cache, first
 
-        jitted = jax.jit(fn)
+        jitted = jax.jit(fn, donate_argnums=1)
         self._prefill_fns[key] = jitted
         return jitted
 
@@ -586,18 +603,19 @@ class ServeEngine:
         — but non-finite garbage is not: additive ``-inf`` mask bias keeps
         NaN NaN, so one retired slot's NaN could leak into other slots'
         scores through FREE-entry clamped page reads.  Shared pages (ref
-        still > 0) are left alone: their content is live prefix data."""
-        self._cache = dict(self._cache)
-        keys = [k for k in ("k", "v", "k_scale", "v_scale") if k in self._cache]
+        still > 0) are left alone: their content is live prefix data.  The
+        write runs in place through a donating jit; the page list pads to
+        ``max_pages`` with an out-of-range id (dropped), one trace for all."""
+        keys = tuple(k for k in ("k", "v", "k_scale", "v_scale") if k in self._cache)
         if self.allocator is not None:
             if not freed:
                 return
-            idx = jnp.asarray(freed, jnp.int32)
-            for key in keys:
-                self._cache[key] = self._cache[key].at[:, idx].set(0)
+            lay = self.allocator.layout
+            idx = np.full((max(lay.max_pages, len(freed)),), lay.num_pages, np.int32)
+            idx[: len(freed)] = freed
         else:
-            for key in keys:
-                self._cache[key] = self._cache[key].at[:, slot].set(0)
+            idx = np.asarray([slot], np.int32)
+        self._cache = self._set_kv(self._cache, (jnp.asarray(idx),), 0, keys=keys)
 
     def _finish_queued(self, req: Request) -> RequestResult:
         """Terminal path for a request that never held a slot this time

@@ -308,11 +308,13 @@ def check_striped_decode():
     ks = jax.random.normal(jax.random.PRNGKey(6), (T, B, 1, Hkv, D))
     vs = jax.random.normal(jax.random.PRNGKey(7), (T, B, 1, Hkv, D))
 
+    # the cache entries take layer stacks: a one-layer stack, layer 0
     def upd(kc, vc, kn, vn, pos):
-        return striped_cache_update(kc, vc, kn, vn, pos, "sp", n)
+        kc, vc = striped_cache_update(kc[None], vc[None], kn, vn, pos, 0, "sp", n)
+        return kc[0], vc[0]
 
     def dec(q, kc, vc, pos):
-        return striped_cache_decode(q, kc, vc, pos, "sp", n)
+        return striped_cache_decode(q, kc[None], vc[None], pos, 0, "sp", n)
 
     upd_f = jax.jit(
         shard_map(
@@ -367,12 +369,17 @@ def check_decode_edge():
     def build(layout, window=None, vec_pos=False, prune=True):
         pos_spec = P(None) if vec_pos else P()
 
+        # the cache entries take layer stacks: a one-layer stack, layer 0
         def upd(kc, vc, kn, vn, pos):
-            return sharded_cache_update(kc, vc, kn, vn, pos, "sp", n, layout=layout)
+            kc, vc = sharded_cache_update(
+                kc[None], vc[None], kn, vn, pos, 0, "sp", n, layout=layout
+            )
+            return kc[0], vc[0]
 
         def dec(q, kc, vc, pos):
             return sharded_cache_decode(
-                q, kc, vc, pos, "sp", n, layout=layout, window=window, prune=prune
+                q, kc[None], vc[None], pos, 0, "sp", n, layout=layout, window=window,
+                prune=prune,
             )
 
         upd_f = jax.jit(shard_map(

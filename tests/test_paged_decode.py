@@ -85,6 +85,18 @@ def _build_pool(rng, depths, page_size, max_pages, extra_pages=0, kv_dtype="fp")
     return out
 
 
+def _stack1(*caches):
+    """One-layer stacks: the cache entries take ``[L, ...]`` stacks + a layer."""
+    return tuple(jnp.asarray(c)[None] for c in caches)
+
+
+def _scales1(k_scale, v_scale):
+    """A quantized pool's scale tables as one-layer stacks (none for fp)."""
+    if k_scale is None:
+        return {}
+    return dict(zip(("k_scale", "v_scale"), _stack1(k_scale, v_scale)))
+
+
 def _oracle_partial(q, dense_k, dense_v, pos, kv_off, stride, window):
     """Gather-then-dense band partial — the exact reference path."""
     hi = (window - 1) if window else da.BAND_INF
@@ -124,8 +136,8 @@ def test_native_matches_gather_oracle(
     if not vector_pos:
         pos = pos.min()  # scalar pos: every row at the same (lowest) depth
     o_n, lse_n = pk.paged_flash_decode(
-        q, k_pool, v_pool, bt, jnp.asarray(pos), shard,
-        stride_kv=stride, window=window, k_scale=k_scale, v_scale=v_scale,
+        q, *_stack1(k_pool, v_pool), bt, jnp.asarray(pos), shard, 0,
+        stride_kv=stride, window=window, **_scales1(k_scale, v_scale),
     )
     o_g, lse_g = _oracle_partial(q, dense_k, dense_v, pos, shard, stride, window)
     atol, rtol = _TOLS[kv_dtype]
@@ -152,7 +164,7 @@ def test_partial_last_page_exact_against_truncated_oracle():
     q = jnp.asarray(rng.normal(size=(len(depths), 1, H, D)), jnp.float32)
     pos = jnp.asarray([d - 1 for d in depths], jnp.int32)
     o_n, lse_n = pk.paged_flash_decode(
-        q, k_pool, v_pool, bt, pos, 0, stride_kv=1
+        q, *_stack1(k_pool, v_pool), bt, pos, 0, 0, stride_kv=1
     )
     for slot, d in enumerate(depths):
         o_ref, lse_ref = ops.block_attention(
@@ -178,7 +190,7 @@ def test_empty_shard_returns_exact_empty_band():
     q = jnp.asarray(rng.normal(size=(1, 1, H, D)), jnp.float32)
     # striped shard i=3 of n=4 sees positions 3, 7, ...; pos=2 hides them all
     o, lse = pk.paged_flash_decode(
-        q, k_pool, v_pool, bt, jnp.int32(2), 3, stride_kv=4
+        q, *_stack1(k_pool, v_pool), bt, jnp.int32(2), 3, 0, stride_kv=4
     )
     np.testing.assert_array_equal(np.asarray(o), 0.0)
     np.testing.assert_array_equal(np.asarray(lse), np.float32(NEG_INF))
@@ -192,13 +204,15 @@ _PS = 16  # serving page size: P = pages_per_block(16) pages per block
 _P = pk.pages_per_block(_PS)
 
 
-def _shuffled_pool(rng, depths, max_pages, kv_dtype):
+def _shuffled_pool(rng, depths, max_pages, kv_dtype, layers=1):
     """Slots at the given LOCAL depths on physical pages drawn from a
     permutation of the pool (two pages no table names); every unwritten
     position poisoned.  A depth of 0 is a free slot: its table row is all
-    -1.  Returns the pool (bf16 / quantized storage as asked), its scale
-    tables or None, the block table, and the dense f32 view the oracle reads
-    (the stored values, dequantized)."""
+    -1.  The pool is a stack of ``layers`` layers sharing the block table,
+    each with its own K/V.  Returns the stacked pools (bf16 / quantized
+    storage as asked), their stacked scale tables or None, the block table,
+    and per layer the dense f32 views the oracle reads (the stored values,
+    dequantized): ``[layers, B, m, Hkv, D]`` for K and for V."""
     counts = [-(-d // _PS) for d in depths]
     num_pages = sum(counts) + 2
     phys = rng.permutation(num_pages)
@@ -210,8 +224,8 @@ def _shuffled_pool(rng, depths, max_pages, kv_dtype):
         taken += c
         flat = np.arange(d)
         used[bt[b, flat // _PS], flat % _PS] = True
-    kv = rng.normal(size=(2, num_pages, _PS, HKV, D)).astype(np.float32)
-    kv = np.where(used[None, ..., None, None], kv, POISON)
+    kv = rng.normal(size=(2, layers, num_pages, _PS, HKV, D)).astype(np.float32)
+    kv = np.where(used[None, None, ..., None, None], kv, POISON)
     scales = (None, None)
     if kv_dtype == "bf16":
         pools = tuple(jnp.asarray(x, jnp.bfloat16) for x in kv)
@@ -223,10 +237,10 @@ def _shuffled_pool(rng, depths, max_pages, kv_dtype):
         stored = tuple(np.asarray(kv_quant.dequantize(c, sc)) for c, sc in qs)
     dense = []
     for x in stored:
-        view = np.zeros((len(depths), max_pages * _PS, HKV, D), np.float32)
+        view = np.zeros((layers, len(depths), max_pages * _PS, HKV, D), np.float32)
         for b, d in enumerate(depths):
             flat = np.arange(d)
-            view[b, :d] = x[bt[b, flat // _PS], flat % _PS]
+            view[:, b, :d] = x[:, bt[b, flat // _PS], flat % _PS]
         dense.append(view)
     return pools, scales, jnp.asarray(bt), dense
 
@@ -261,17 +275,52 @@ def test_block_walk_matches_gather_oracle(case, kv_dtype):
     # engine leaves it; every other row attends to its last written slot
     pos = np.asarray([kv_off + stride * max(d - 1, 0) for d in depths], np.int32)
     o_n, lse_n = pk.paged_flash_decode(
-        q, k_pool, v_pool, bt, jnp.asarray(pos), kv_off,
+        q, k_pool, v_pool, bt, jnp.asarray(pos), kv_off, 0,
         stride_kv=stride, window=window, k_scale=k_scale, v_scale=v_scale,
     )
     np.testing.assert_array_equal(np.asarray(o_n[0]), 0.0)
     np.testing.assert_array_equal(np.asarray(lse_n[0]), np.float32(NEG_INF))
-    o_g, lse_g = _oracle_partial(q, dense_k, dense_v, pos, kv_off, stride, window)
+    o_g, lse_g = _oracle_partial(q, dense_k[0], dense_v[0], pos, kv_off, stride, window)
     atol, rtol = _TOLS[kv_dtype]
     np.testing.assert_allclose(np.asarray(o_n[1:]), np.asarray(o_g[1:]), atol=atol, rtol=rtol)
     np.testing.assert_allclose(
         np.asarray(lse_n[1:]), np.asarray(lse_g[1:]), atol=atol, rtol=rtol
     )
+
+
+@pytest.mark.parametrize("geometry", ["whole", "shard"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_layer_index_reads_its_layer_of_the_stack(kv_dtype, geometry):
+    """The kernel takes the model's stacked ``[L, num_pages, ...]`` pool and
+    a layer index: every layer of a 3-layer stack (one block table, its own
+    K/V and scales in each layer) must match the gather oracle over THAT
+    layer, for a whole pool and a striped shard's (stride 2, offset 1), the
+    index traced as in the model's layer loop; a free slot far past any
+    depth still reads nothing."""
+    stride, kv_off = {"whole": (1, 0), "shard": (2, 1)}[geometry]
+    depths = [0, 5, _PS + 3, _P * _PS + 7]
+    rng = np.random.default_rng(11)
+    max_pages = -(-max(depths) // _PS) + 1
+    (k_pool, v_pool), (k_scale, v_scale), bt, (dense_k, dense_v) = _shuffled_pool(
+        rng, depths, max_pages, kv_dtype, layers=3
+    )
+    q = jnp.asarray(rng.normal(size=(len(depths), 1, H, D)), jnp.float32)
+    pos = np.asarray([kv_off + stride * max(d - 1, 0) for d in depths], np.int32)
+    pos[0] = 2**30  # the free slot's position runs on past any depth, as a parked slot's
+    decode = jax.jit(lambda layer: pk.paged_flash_decode(
+        q, k_pool, v_pool, bt, jnp.asarray(pos), kv_off, layer,
+        stride_kv=stride, k_scale=k_scale, v_scale=v_scale,
+    ))
+    atol, rtol = _TOLS[kv_dtype]
+    for layer in range(3):
+        o_n, lse_n = decode(jnp.int32(layer))
+        o_g, lse_g = _oracle_partial(q, dense_k[layer], dense_v[layer], pos, kv_off,
+                                     stride, None)
+        np.testing.assert_array_equal(np.asarray(lse_n[0]), np.float32(NEG_INF))
+        np.testing.assert_allclose(np.asarray(o_n[1:]), np.asarray(o_g[1:]),
+                                   atol=atol, rtol=rtol, err_msg=f"layer {layer}")
+        np.testing.assert_allclose(np.asarray(lse_n[1:]), np.asarray(lse_g[1:]),
+                                   atol=atol, rtol=rtol, err_msg=f"layer {layer}")
 
 
 # --------------------------------------------------------------------------
@@ -308,8 +357,7 @@ def test_cow_shared_page_decode():
     q = jnp.asarray(rng.normal(size=(2, 1, H, D)), jnp.float32)
     pos = jnp.asarray([3, 2], jnp.int32)
     o_n, lse_n = pk.paged_flash_decode(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool), bt, pos, 0,
-        stride_kv=1,
+        jnp.asarray(q), *_stack1(k_pool, v_pool), bt, pos, 0, 0, stride_kv=1,
     )
     dense_k = np.zeros((2, lay.max_pages * page_size, HKV, D), np.float32)
     dense_v = np.zeros_like(dense_k)
@@ -334,10 +382,10 @@ def test_dense_split_k_matches_band(m, window):
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
     pos = jnp.asarray(rng.integers(0, m, (B,)), jnp.int32)
     o_band = da.sharded_cache_decode(
-        q, k_cache, v_cache, pos, None, 1, window=window, kernel="band"
+        q, *_stack1(k_cache, v_cache), pos, 0, None, 1, window=window, kernel="band"
     )
     o_native = da.sharded_cache_decode(
-        q, k_cache, v_cache, pos, None, 1, window=window, kernel="native"
+        q, *_stack1(k_cache, v_cache), pos, 0, None, 1, window=window, kernel="native"
     )
     np.testing.assert_allclose(
         np.asarray(o_native), np.asarray(o_band), atol=2e-5, rtol=1e-5
@@ -370,9 +418,8 @@ def test_decode_step_kernel_flag_paged_n1(kv_dtype):
     outs, pools = {}, {}
     for kernel in ("gather", "native"):
         out = dispatch.decode_attention_step(
-            q, kn, vn, k_pool, v_pool, pos, ctx,
-            block_table=bt, decode_kernel=kernel,
-            k_scale=scales[0], v_scale=scales[1],
+            q, kn, vn, *_stack1(k_pool, v_pool), pos, ctx, layer=0,
+            block_table=bt, decode_kernel=kernel, **_scales1(*scales),
         )
         outs[kernel] = np.asarray(out[0])
         # quantized: the updated scale tables ride along and must match too
@@ -395,8 +442,9 @@ def test_native_falls_back_to_gather_under_ref_backend():
     pos = jnp.asarray([4], jnp.int32)
     ops.set_backend("ref")
     try:
-        o_n = da.paged_cache_decode(q, k_pool, v_pool, bt, pos, None, 1, kernel="native")
-        o_g = da.paged_cache_decode(q, k_pool, v_pool, bt, pos, None, 1, kernel="gather")
+        pools = _stack1(k_pool, v_pool)
+        o_n = da.paged_cache_decode(q, *pools, bt, pos, 0, None, 1, kernel="native")
+        o_g = da.paged_cache_decode(q, *pools, bt, pos, 0, None, 1, kernel="gather")
     finally:
         ops.set_backend("auto")
     np.testing.assert_array_equal(np.asarray(o_n), np.asarray(o_g))
@@ -419,8 +467,8 @@ def test_plan_key_distinguishes_decode_kernel():
     with pytest.raises(ValueError):
         dispatch.decode_attention_step(
             jnp.zeros((1, 1, H, D)), jnp.zeros((1, 1, HKV, D)),
-            jnp.zeros((1, 1, HKV, D)), jnp.zeros((1, 8, HKV, D)),
-            jnp.zeros((1, 8, HKV, D)), jnp.int32(0), ParallelCtx(),
+            jnp.zeros((1, 1, HKV, D)), jnp.zeros((1, 1, 8, HKV, D)),
+            jnp.zeros((1, 1, 8, HKV, D)), jnp.int32(0), ParallelCtx(), layer=0,
             decode_kernel="nativ",
         )
 

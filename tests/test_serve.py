@@ -408,3 +408,100 @@ def test_paged_admission_defers_on_small_pool():
     oracle = ServeEngine(cfg, params, max_seq=64, num_slots=1)
     for rid, p in zip(rids, prompts):
         assert fin[rid].generated == oracle.generate(p[None], 8)[0].tolist()
+
+
+# --------------------------------------------------------------------------
+# donation: every jitted step updates the cache in place, over many ticks
+# --------------------------------------------------------------------------
+
+_DONATED_RUNS = {
+    "dense": dict(),
+    "paged": dict(paged=True, page_size=4),
+    "chunked-prefill": dict(paged=True, page_size=4, prefill_chunk=8, tick_token_budget=12),
+    "preempt-recompute": dict(paged=True, page_size=4, num_pages=13, prefill_chunk=8,
+                              oversubscribe=2.0),
+    "prefix-cow": dict(paged=True, page_size=4, prefill_chunk=8),
+    "spec-verify": dict(paged=True, page_size=4, prefill_chunk=8, spec_k=4),
+    "scrub-numeric": dict(paged=True, page_size=4, prefill_chunk=8),
+}
+
+
+@pytest.mark.parametrize("run", sorted(_DONATED_RUNS))
+def test_donated_engine_logits_match_forward(run):
+    """Multi-tick engine runs whose steps donate the cache: the first tick
+    consumes the pool the engine was built with, no path reads a donated
+    buffer afterwards, and every token served is the model's plain forward
+    over the request's context (the same token; logits to fp reassociation,
+    as in test_continuous_prefill.py).  Covers preemption with recompute,
+    copy-on-write prefix sharing, chunked prefill, speculative verify, and
+    the numeric-error scrub, which rewrites the pool through a donating jit
+    too."""
+    from repro.serve.config import ServeConfig
+
+    cfg = get_config("granite-8b").reduced()
+    params = tfm.init_params(cfg, jax.random.PRNGKey(1))
+    rng = np.random.default_rng(23)
+    if run == "prefix-cow":
+        # two requests share an 8-token prefix (two whole pages)
+        prefix = rng.integers(0, cfg.vocab_size, (8,), dtype=np.int32)
+        prompts = [np.concatenate([prefix, rng.integers(0, cfg.vocab_size, (n,),
+                                                        dtype=np.int32)]) for n in (4, 3)]
+        prompts.append(rng.integers(0, cfg.vocab_size, (16,), dtype=np.int32))
+    elif run == "spec-verify":
+        # repeating prompts: the n-gram drafter finds drafts to verify
+        loop = rng.integers(0, cfg.vocab_size, (4,), dtype=np.int32)
+        prompts = [np.tile(loop, 4), np.tile(loop[::-1], 3), np.tile(loop, 2)]
+    else:
+        prompts = [rng.integers(0, cfg.vocab_size, (n,), dtype=np.int32)
+                   for n in (16, 11, 16)]
+    eng = ServeEngine(cfg, params, serve=ServeConfig(max_seq=64, num_slots=3,
+                                                     **_DONATED_RUNS[run]))
+    eng.capture_logits = True
+    built = eng._cache["k"]
+    rids = [eng.submit(p, 12) for p in prompts]
+    hit = False
+    while eng.has_work:
+        if not hit and eng.scheduler.slots[1] is not None \
+                and eng.scheduler.slots[1].generated:
+            hit = True
+            if run == "scrub-numeric":
+                eng.poison_slot_cache(1)
+            elif run == "prefix-cow":
+                # the sharer takes a private copy of the first shared page,
+                # as a write into it would (ensure_append's copy-on-write),
+                # and decodes on through the copy
+                cow = eng.allocator.ensure_append(1, 0)
+                assert cow is not None
+                eng._apply_copies([cow])
+        eng.step()
+    assert built.is_deleted()  # donated to the first step, never read again
+    results = [eng._finished[r] for r in rids]
+    stats = eng.kv_cache_stats()
+    expect = {
+        "preempt-recompute": stats.get("preemptions", 0) > 0,
+        "prefix-cow": stats.get("shared_hits", 0) >= 1 and stats.get("cow_copies", 0) >= 1,
+        "spec-verify": eng.spec_accepted > 0,
+        "chunked-prefill": eng.chunk_launches > len(prompts),
+        "scrub-numeric": [r.status for r in results].count("numeric_error") == 1,
+    }
+    assert expect.get(run, True), (run, stats)
+    for key in ("k", "v"):  # the scrub left nothing non-finite in the pool
+        assert np.isfinite(np.asarray(eng._cache[key])).all()
+
+    @jax.jit
+    def forward(tokens):
+        S = tokens.shape[1]
+        batch = {"tokens": tokens, "positions": jnp.arange(S, dtype=jnp.int32)}
+        return tfm.forward(params, cfg, CTX, batch)[0][0]
+
+    served = 0
+    for rid, prompt, res in zip(rids, prompts, results):
+        if res.status != "ok":
+            continue
+        seq = np.concatenate([prompt, np.asarray(res.generated, np.int32)])
+        want = np.asarray(forward(jnp.asarray(seq[None, :-1])))[len(prompt) - 1:]
+        got = np.stack(eng.debug_logits[rid])
+        assert res.generated == np.argmax(want, axis=-1).tolist(), (run, rid)
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5, err_msg=f"{run} {rid}")
+        served += 1
+    assert served >= 2

@@ -5,16 +5,20 @@ Mosaic's refusals (block shapes that break the (8, 128) tiling, unaligned
 in-kernel slices, VMEM overruns) fail here instead of on the chip.  The
 attention shapes are granite-8b's at real widths: 32 query heads over 8 KV
 heads, head_dim 128, 128x128 blocks, bf16; the SSD scan runs at Mamba-2's
-64-token chunks.  Nothing runs.
+64-token chunks.  The serving engine's jitted steps are compiled too, to check
+that they update the paged pool in place.  Nothing runs.
 
 The topology is described inside a fixture: only one process at a time may
 load the TPU library, so no module-import-time code may touch it.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -23,6 +27,7 @@ from repro.kernels import paged_decode as pk
 from repro.kernels import ssd_scan as ssd
 
 B, S, H, HKV, D = 1, 2048, 32, 8, 128
+LAYERS = 8  # the serving cell's stacked pool: the kernel reads one layer of it
 
 
 @pytest.fixture(scope="module")
@@ -85,25 +90,27 @@ def test_flash_attention_compiles_for_v5e(one_chip, direction, segments):
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
 def test_paged_decode_compiles_for_v5e(one_chip, kv_dtype):
-    """The serving pool: 4 slots, 16-token pages, max_seq 4096."""
+    """The serving pool: 4 slots, 16-token pages, max_seq 4096, one layer
+    of an 8-layer stack."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     slots, page, max_pages = 4, 16, 256
     elem = {"bf16": jnp.bfloat16, "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}[kv_dtype]
     q = sds((slots, 1, H, D), jnp.bfloat16)
-    pool = sds((slots * max_pages, page, HKV, D), elem)
-    scale = sds((slots * max_pages, page, HKV), jnp.float32)
+    pool = sds((LAYERS, slots * max_pages, page, HKV, D), elem)
+    scale = sds((LAYERS, slots * max_pages, page, HKV), jnp.float32)
     bt = sds((slots, max_pages), jnp.int32)
     pos = sds((slots,), jnp.int32)
+    layer = sds((), jnp.int32)
     quant = kv_dtype != "bf16"
 
-    def f(q, k, v, bt, pos, ks, vs):
+    def f(q, k, v, bt, pos, layer, ks, vs):
         return pk.paged_flash_decode(
-            q, k, v, bt, pos, 0, stride_kv=1, interpret=False,
+            q, k, v, bt, pos, 0, layer, stride_kv=1, interpret=False,
             k_scale=ks if quant else None, v_scale=vs if quant else None,
         )
-    _compile(f, q, pool, pool, bt, pos, scale, scale)
+    _compile(f, q, pool, pool, bt, pos, layer, scale, scale)
 
 
 _CELL_POOLS = {
@@ -130,19 +137,20 @@ def test_paged_decode_compiles_at_cell_shapes(one_chip, pool):
 
     quant = elem in (jnp.int8, jnp.float8_e4m3fn)
     q = sds((slots, 1, H, D), jnp.bfloat16)
-    kv = sds((num_pages, page, HKV, D), elem)
-    scale = sds((num_pages, page, HKV), jnp.float32)
+    kv = sds((LAYERS, num_pages, page, HKV, D), elem)
+    scale = sds((LAYERS, num_pages, page, HKV), jnp.float32)
     bt = sds((slots, max_pages), jnp.int32)
     pos = sds((slots,), jnp.int32)
     off = sds((), jnp.int32)  # traced, as under shard_map
+    layer = sds((), jnp.int32)  # traced, as in the model's layer loop
 
-    def f(q, k, v, bt, pos, off, ks, vs):
+    def f(q, k, v, bt, pos, off, layer, ks, vs):
         return pk.paged_flash_decode(
-            q, k, v, bt, pos, off, stride_kv=stride, window=window,
+            q, k, v, bt, pos, off, layer, stride_kv=stride, window=window,
             interpret=False, k_scale=ks if quant else None,
             v_scale=vs if quant else None,
         )
-    _compile(f, q, kv, kv, bt, pos, off, scale, scale)
+    _compile(f, q, kv, kv, bt, pos, off, layer, scale, scale)
 
 
 @pytest.mark.parametrize("groups", [1, 2])
@@ -158,3 +166,95 @@ def test_ssd_scan_compiles_for_v5e(one_chip, groups):
     bc = sds((B, S, groups, n), jnp.bfloat16)
     _compile(lambda *args: ssd.ssd_scan_fwd(*args, chunk=64, interpret=False),
              x, dt, a, bc, bc)
+
+
+# --------------------------------------------------------------------------
+# the serving steps update the paged pool in place
+# --------------------------------------------------------------------------
+
+# a 3-layer granite-shaped model with 128-wide heads (the kernel's lane
+# tiling), whose pool is too large for on-chip memory as at the cell's size:
+# 3 layers x 8,192 pages of 8 tokens x 2 KV heads x 128
+_POOL = (3, 8192, 8, 2, 128)
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    from repro.configs import get_config
+    from repro.models import transformer as tfm
+    from repro.serve.config import ServeConfig
+    from repro.serve.engine import ServeEngine
+
+    cfg = dataclasses.replace(get_config("granite-8b").reduced(), num_layers=_POOL[0],
+                              head_dim=_POOL[4])
+    params = tfm.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)
+    # the engine holds a small pool here; the steps are compiled for _POOL
+    return ServeEngine(cfg, params, serve=ServeConfig(
+        max_seq=64, num_slots=4, paged=True, page_size=_POOL[2], num_pages=37,
+        cache_dtype=jnp.bfloat16, decode_kernel="native",
+    ))
+
+
+def _step_args(eng, step, one_chip):
+    """(jitted step, abstract args on the described chip) of one serving step."""
+    def on(x):
+        return jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on, eng.params)
+    cache = jax.tree.map(on, eng._cache)
+    cache["k"] = cache["v"] = jax.ShapeDtypeStruct(_POOL, jnp.bfloat16, sharding=one_chip)
+    B = eng.num_slots
+    if step == "decode":
+        return eng._decode, (params, cache, i32(B, 1))
+    if step == "packed-prefill":
+        return eng._get_prefill_packed(32, 2), (params, cache, i32(1, 32), i32(2), i32(2),
+                                                i32(2))
+    if step == "prefill":
+        return eng._get_prefill(32), (params, cache, i32(1, 32), i32(), i32(), i32())
+    if step == "chunk":
+        return eng._chunk_step, (params, cache, i32(B, 8), i32(B), i32(B), i32(B), i32(B))
+    return eng._verify, (params, cache, i32(B, 4), i32(B), i32(B))
+
+
+@pytest.mark.parametrize("step", ["decode", "packed-prefill", "prefill", "chunk", "verify"])
+def test_serving_step_updates_pool_in_place(one_chip, tiny_engine, monkeypatch, step):
+    """Compiled for the chip, each jitted serving step takes the stacked pool
+    as a donated input and returns it aliased, produces no array the size of
+    one layer's slice of the pool (the model carries the stack whole through
+    its layer loop and the kernel reads ``pool.at[layer, page]``), and needs
+    less scratch memory than one such slice."""
+    # code that asks the platform sees the CPU here: compile the kernel, not
+    # its interpreter, for the described chip
+    monkeypatch.setattr(pk, "resolve_interpret", lambda interpret=None: False)
+    jitted, args = _step_args(tiny_engine, step, one_chip)
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    if step == "decode":
+        assert "tpu_custom_call" in text  # the paged kernel itself
+
+    # 1. the K and V stacks are aliased input -> output
+    paths = [p for p, _ in jax.tree_util.tree_flatten_with_path(args)[0]]
+    pool_params = {
+        i for i, p in enumerate(paths)
+        if p[0] == jax.tree_util.SequenceKey(1)
+        and p[1] in (jax.tree_util.DictKey("k"), jax.tree_util.DictKey("v"))
+    }
+    header = text.splitlines()[0]
+    aliased = {int(m) for m in re.findall(r"\}: \((\d+), \{\}", header)}
+    assert len(pool_params) == 2 and pool_params <= aliased, header[:400]
+
+    # 2. no op yields one layer's slice, in any shape (a bitcast included)
+    slice_elems = int(np.prod(_POOL[1:]))
+    sliced = []
+    for line in text.splitlines():
+        for dims in re.findall(r"= \(?\w+\[([\d,]+)\]", line):
+            if int(np.prod([int(d) for d in dims.split(",")])) == slice_elems:
+                sliced.append(line.strip()[:200])
+    assert not sliced, sliced[:4]
+
+    # 3. scratch: under one layer's slice of one of the two stacks
+    slice_bytes = slice_elems * jnp.dtype(jnp.bfloat16).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < slice_bytes
